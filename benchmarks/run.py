@@ -27,6 +27,8 @@ def main() -> int:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of: " + ",".join(ALL))
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(ALL)
     failures = 0
     for name in names:

@@ -117,8 +117,7 @@ def _bench_backends(rep: Report, rows: list) -> None:
         hw_b = stack_configs([hws[i % len(hws)] for i in range(B)])
         for backend in ("xla", "pallas"):
             fn = jax.jit(dse.make_sweep_fn(
-                k.program, prof, max_steps=k.max_steps, backend=backend,
-                blk_b=min(32, B)))
+                k.program, prof, max_steps=k.max_steps, backend=backend))
 
             def run_batch():
                 jax.block_until_ready(fn(mems, hw_b))

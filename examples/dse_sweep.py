@@ -5,7 +5,7 @@ Sweeps the full (kernel program x hardware topology x data image) grid
 through the fused simulate+estimate path in ONE call -- the programs are
 packed to a common padded shape (`pack_programs`) and swept as data, so
 G kernels cost one compile instead of G.  Vmapped, jitted, and (when
-devices exist) mesh-sharded with pjit.  On a 512-chip pod the same code
+devices exist) mesh-sharded with shard_map.  On a 512-chip pod the same code
 sweeps ~10^6 design points per compile; here it runs on whatever
 jax.devices() shows.
 

@@ -47,7 +47,8 @@ except ImportError:          # non-POSIX: saves fall back to atomic
 # sweep API, so AUTO must be distinguishable from an explicit None.
 AUTO = "auto"
 
-DEFAULT_BLK_B = 32
+DEFAULT_BLK_B = 32       # XLA path: lane block of the eager dispatch
+PALLAS_BLK_B = 128       # Pallas lane tile: one TPU vreg, the smallest legal
 DEFAULT_CHUNK_STEPS = 64
 DEFAULT_MAX_BUCKETS = 4
 CACHE_VERSION = 1
@@ -58,6 +59,11 @@ _ENV_ENABLE = "REPRO_AUTOTUNE"
 def is_auto(*values) -> bool:
     """True if ANY of the values is the AUTO sentinel."""
     return any(isinstance(v, str) and v == AUTO for v in values)
+
+
+def default_blk_b(backend: str) -> int:
+    """The untuned lane tile of a backend."""
+    return PALLAS_BLK_B if backend == "pallas" else DEFAULT_BLK_B
 
 
 def autotune_enabled(flag: Optional[bool] = None) -> bool:
@@ -268,7 +274,7 @@ class AutotuneCache:
 
         return TunedConfig(
             blk_b=int(pick(blk_b, cached.blk_b if cached else None,
-                           DEFAULT_BLK_B)),
+                           default_blk_b(shape.backend))),
             chunk_steps=pick(chunk_steps,
                              cached.chunk_steps if cached else None,
                              DEFAULT_CHUNK_STEPS),
@@ -318,7 +324,8 @@ def default_candidates(shape: ShapeClass, max_steps: int) -> List[dict]:
     buckets = sorted({b for b in (1, 2, 4, min(shape.G, 8))
                       if 1 <= b <= shape.G})
     chunks = sorted({c for c in (32, 64, 128) if c <= max(max_steps, 32)})
-    blks = (16, 32) if shape.backend == "pallas" else (32,)
+    blks = ((PALLAS_BLK_B, 2 * PALLAS_BLK_B) if shape.backend == "pallas"
+            else (DEFAULT_BLK_B,))
     return [dict(max_buckets=b, chunk_steps=c, blk_b=k)
             for b in buckets for c in chunks for k in blks]
 

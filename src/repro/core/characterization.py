@@ -20,6 +20,7 @@ Conventions chosen where the paper is silent (documented per DESIGN.md):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
@@ -247,23 +248,9 @@ def characterize(hw: Optional[HwConfig] = None,
                    t_clk_ns=float(np.asarray(hw.t_clk_ns)))
 
 
-_DEFAULT_CACHE = "/tmp/repro_profile_cache.npz"
-
-
-def default_profile(cache_path: str = _DEFAULT_CACHE,
-                    refresh: bool = False) -> Profile:
-    """The baseline-hardware characterization, cached on disk -- profiling
-    is a one-time cost in the paper's workflow (Figure 1) and the cache
-    plays the role of the checked-in characterization file."""
-    import os
-    if not refresh and os.path.exists(cache_path):
-        try:
-            return Profile.load(cache_path)
-        except Exception:
-            pass
-    prof = characterize()
-    try:
-        prof.save(cache_path)
-    except OSError:
-        pass
-    return prof
+@functools.lru_cache(maxsize=None)
+def default_profile() -> Profile:
+    """The baseline-hardware characterization, built by the committed code
+    once per process -- profiling is the paper's one-time cost (Figure 1)
+    and takes seconds here, so no file outlives the code that built it."""
+    return characterize()

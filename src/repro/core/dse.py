@@ -16,10 +16,10 @@ mesh-sharded computation over all THREE design-space axes:
     full simulate->estimate path stays inside one jitted program -- no
     host round-trip per design point;
   * sweep() shards the flattened (program x hw x data) grid over every
-    device of the mesh -- pjit for the XLA scan path, shard_map for the
-    fused Pallas engine (each device runs its own VMEM-resident sweep
-    over its shard): on the production pod this is a 512-way
-    data-parallel sweep, the deployable version of the paper's tool.
+    device of the mesh with one ``shard_map`` builder for both backends
+    (each device sweeps its own shard): on a 512-chip pod this is a
+    512-way data-parallel sweep, the deployable version of the paper's
+    tool.
 
 Different *mappings* (programs) are packed to a common padded shape by
 ``program.pack_programs`` and swept as data: ONE compiled executable per
@@ -37,8 +37,8 @@ import numpy as np
 
 from . import isa
 from ..analysis import pareto as _pareto
-from .autotune import AUTO, ShapeClass, autotune_enabled, default_cache, \
-    is_auto, tune_sweep
+from .autotune import (AUTO, ShapeClass, autotune_enabled, default_blk_b,
+                       default_cache, is_auto, tune_sweep)
 from .cgra import init_state, make_exec_fn, rows_from_fused
 from .characterization import Profile
 from .hwconfig import HwConfig, stack_configs
@@ -53,27 +53,6 @@ from .program import (MappingSet, Program, ProgramBatch, as_program_batch,
 # program).  Tests use deltas of these to assert that sweeping G kernels
 # compiles once and that same-shape program swaps hit the jit cache.
 TRACE_COUNTS: Dict[str, int] = {"xla": 0, "pallas": 0}
-
-
-def _shard_map(f, mesh, *, in_specs, out_specs):
-    """Version-portable shard_map with replication checking off (required
-    around pallas_call).  jax >= 0.5 exports a stable ``jax.shard_map``
-    whose mesh is keyword-only and whose flag is ``check_vma``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map`` with positional mesh and
-    ``check_rep``."""
-    try:
-        from jax import shard_map as sm              # stable, jax >= 0.5
-        kwargs = {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        kwargs = {"check_rep": False}
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kwargs)
-    except TypeError:
-        # intermediate releases: stable export, pre-rename flag
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
 
 
 class SweepResult(NamedTuple):
@@ -191,7 +170,10 @@ def _sweep_body(exec_step, fused, base, n_instrs, tbl, mem_init,
                                       (jnp.int32(0), carry0))
     final, e_uwcc, _, n_exec = carry
     lat_cc = final.t_cc
-    energy_pj = e_uwcc * tbl["t_clk_ns"] * 1e-3
+    # one rounding of (t_clk * 1e-3) whether the profile is an operand or
+    # a folded constant (a mesh sweep closes over it): bit-identical
+    # energies on 1 and N devices
+    energy_pj = e_uwcc * (tbl["t_clk_ns"] * 1e-3)
     power_mw = e_uwcc / jnp.maximum(lat_cc, 1) * 1e-3
     checksum = (final.mem * (jnp.arange(mem_size, dtype=jnp.int32) | 1)
                 ).sum().astype(jnp.int32)
@@ -249,7 +231,8 @@ def make_sweep_fn(program: Union[Program, ProgramBatch, Sequence[Program]],
                   profile: Profile, *, rows: int = 4,
                   cols: int = 4, mem_size: int = 4096, max_steps: int = 2048,
                   backend: str = "xla", chunk_steps: Optional[int] = 64,
-                  blk_b: int = 32, interpret: Optional[bool] = None,
+                  blk_b: Union[int, None, str] = AUTO,
+                  interpret: Optional[bool] = None,
                   max_banks: Optional[int] = None,
                   validate: bool = True,
                   reduce: Optional[_pareto.Reduction] = None):
@@ -288,7 +271,9 @@ def make_sweep_fn(program: Union[Program, ProgramBatch, Sequence[Program]],
     ``pallas_call``; on the XLA operand path it is the lane-block size of
     the eager dispatch (cache-residency -- see the comment in ``fn``),
     autotunable per shape class via ``core.autotune``.  ``None`` disables
-    lane blocking.  Results are bit-identical for any value.
+    lane blocking.  ``AUTO`` takes the backend's own default
+    (``autotune.default_blk_b``).  Results are bit-identical for any
+    value.
 
     max_banks: static bank-scoreboard bound of the contention model;
     ``None`` keeps the 16-slot default.  Configs with more banks than the
@@ -310,6 +295,8 @@ def make_sweep_fn(program: Union[Program, ProgramBatch, Sequence[Program]],
     """
     if max_banks is None:
         max_banks = DEFAULT_MAX_BANKS
+    if is_auto(blk_b):
+        blk_b = default_blk_b(backend)
     if reduce is not None and isinstance(program, Program):
         raise ValueError("reduce= needs the batch API; pass a sequence "
                          "of programs or a ProgramBatch")
@@ -353,7 +340,7 @@ def make_sweep_fn(program: Union[Program, ProgramBatch, Sequence[Program]],
             # the cached executable is driven over <= blk_b-lane blocks
             # and the results concatenated -- bit-identical (lanes are
             # independent) and still one trace (every block has the
-            # same padded shape).  Skipped under an outer jit/pjit
+            # same padded shape).  Skipped under an outer jit/shard_map
             # (mesh path): blocking is a dispatch-level optimization
             # and python-slicing a sharded operand would just reshard.
             if (blk_b is None or B <= blk_b
@@ -448,43 +435,54 @@ def plan_grid(program: Union[Program, ProgramBatch, Sequence[Program], None]
     return GridPlan(batch, images, img_idx, prog_idx, hw_grid, max_banks)
 
 
-def _reduced_shard_call(fn, images, mesh, spec, n_devices: int):
-    """SPMD reduced sweep: every device sweeps its shard of the flat grid
-    and reduces it on device to a ``(G, K)`` candidate set; only the
-    gathered ``n_devices * G * K`` candidates cross to the host, where the
-    associative ``merge_reduced`` recovers exactly the monolithic answer.
-    Works for both backends (the XLA scan core and the Pallas engine are
-    both shard_map-able); padded lanes carry ``lane_idx = -1``."""
+def _shard_call(fn, images, mesh, reduce=None):
+    """THE mesh path of every sweep, both backends, reduced or not.
+
+    Each device runs ``fn`` (the XLA operand core or the Pallas engine)
+    under ``jax.shard_map`` on its shard of the flat grid, gathering its
+    memory images by index from the replicated ``(D, M)`` stack.  The
+    returned ``call(idx, hw, gi[, lane])`` takes lane rows whose length
+    divides the device count.  Unreduced, the result stays sharded over
+    the mesh.  Reduced, every device reduces its shard to a ``(G, K)``
+    candidate set on device (padded lanes carry ``lane_idx = -1``) and
+    only the gathered ``n_devices * G * K`` candidates reach the host,
+    where ``merge_reduced`` recovers exactly the monolithic answer."""
     from jax.sharding import PartitionSpec
 
     from ..parallel.sharding import flat_batch_spec
     flat = flat_batch_spec(mesh)
+    n_dev = int(mesh.devices.size)
 
-    def shard_fn(imgs, idx, gi, lane, hw):
-        red = fn(jnp.take(imgs, idx, axis=0), hw, gi, lane)
-        return jax.tree.map(lambda x: x[None], red)
+    def shard_fn(imgs, idx, gi, hw, *lane):
+        out = fn(jnp.take(imgs, idx, axis=0), hw, gi, *lane)
+        return out if reduce is None else jax.tree.map(lambda x: x[None],
+                                                        out)
 
-    sharded = jax.jit(_shard_map(
-        shard_fn, mesh,
-        in_specs=(PartitionSpec(), flat, flat, flat, flat),
-        out_specs=flat))
+    n_lane_args = 3 if reduce is None else 4
+    sharded = jax.jit(jax.shard_map(
+        shard_fn, mesh=mesh,
+        in_specs=(PartitionSpec(),) + (flat,) * n_lane_args,
+        out_specs=flat, check_vma=False))
 
-    def call(idx, gi, lane, hw) -> _pareto.ReducedResult:
-        out = sharded(images, jnp.asarray(idx, jnp.int32),
-                      jnp.asarray(gi, jnp.int32),
-                      jnp.asarray(lane, jnp.int32), hw)
+    def call(idx, hw, gi, *lane):
+        out = sharded(images, jnp.asarray(idx, jnp.int32), jnp.asarray(
+            gi, jnp.int32), hw, *(jnp.asarray(x, jnp.int32) for x in lane))
+        if reduce is None:
+            return out
         stacked = [np.asarray(leaf) for leaf in out]
         parts = [_pareto.ReducedResult(*(leaf[i] for leaf in stacked))
-                 for i in range(n_devices)]
-        return _pareto.merge_reduced(spec, parts)
+                 for i in range(n_dev)]
+        return _pareto.merge_reduced(reduce, parts)
 
+    call.sharded = sharded        # the SPMD program, for AOT compilation
     return call
 
 
 def make_grid_fn(plan: GridPlan, profile: Profile, *,
                  max_steps: int = 2048, mem_size: int = 4096,
                  backend: str = "xla", chunk_steps: Optional[int] = 64,
-                 blk_b: int = 32, interpret: Optional[bool] = None,
+                 blk_b: Union[int, None, str] = AUTO,
+                 interpret: Optional[bool] = None,
                  mesh: Optional[jax.sharding.Mesh] = None,
                  reduce: Optional[_pareto.Reduction] = None):
     """Unit-sliceable sweep core: ``fn(img_idx, hw_slice, prog_idx) ->
@@ -495,10 +493,9 @@ def make_grid_fn(plan: GridPlan, profile: Profile, *,
     result is bit-identical whether it runs in a monolithic sweep or
     inside any unit partition (lanes are independent).
 
-    With ``mesh`` the slice runs SPMD over its devices (shard_map for
-    the Pallas engine, pjit for XLA, as in ``sweep``); slice lengths
-    must then divide the device count -- the sweep runner pads its
-    units accordingly.
+    With ``mesh`` the slice runs SPMD over its devices through the same
+    ``shard_map`` builder as ``sweep``; slice lengths must then divide
+    the device count -- the sweep runner pads its units accordingly.
 
     With ``reduce`` the signature gains a trailing ``lane_idx`` row
     (original flat grid index per lane, -1 for padded lanes) and the fn
@@ -513,59 +510,13 @@ def make_grid_fn(plan: GridPlan, profile: Profile, *,
                        validate=False, reduce=reduce)
     images = plan.images
     if mesh is None:
-        if reduce is not None:
-            def grid_fn(idx, hw, gi, lane):
-                return fn(jnp.take(images, jnp.asarray(idx, jnp.int32),
-                                   axis=0),
-                          hw, jnp.asarray(gi, jnp.int32),
-                          jnp.asarray(lane, jnp.int32))
-            return grid_fn
-
-        def grid_fn(idx, hw, gi):
+        def grid_fn(idx, hw, gi, *lane):
             return fn(jnp.take(images, jnp.asarray(idx, jnp.int32), axis=0),
-                      hw, jnp.asarray(gi, jnp.int32))
+                      hw, jnp.asarray(gi, jnp.int32),
+                      *(jnp.asarray(x, jnp.int32) for x in lane))
         return grid_fn
 
-    if reduce is not None:
-        call = _reduced_shard_call(fn, images, mesh, reduce,
-                                   int(mesh.devices.size))
-
-        def grid_fn(idx, hw, gi, lane):
-            return call(idx, gi, lane, hw)
-        return grid_fn
-
-    from ..parallel.sharding import (batch_sharding, flat_batch_spec,
-                                     replicated_sharding)
-    if backend == "pallas":
-        from jax.sharding import PartitionSpec
-
-        def shard_fn(imgs, idx, gi, hw):
-            return fn(jnp.take(imgs, idx, axis=0), hw, gi)
-
-        sharded = jax.jit(_shard_map(
-            shard_fn, mesh,
-            in_specs=(PartitionSpec(), flat_batch_spec(mesh),
-                      flat_batch_spec(mesh), flat_batch_spec(mesh)),
-            out_specs=flat_batch_spec(mesh)))
-
-        def grid_fn(idx, hw, gi):
-            return sharded(images, jnp.asarray(idx, jnp.int32),
-                           jnp.asarray(gi, jnp.int32), hw)
-        return grid_fn
-
-    sh = batch_sharding(mesh)
-    rep = replicated_sharding(mesh)
-    jitted = jax.jit(
-        lambda idx, hw, gi: fn(jnp.take(images, idx, axis=0), hw, gi),
-        in_shardings=(sh, jax.tree.map(lambda _: sh, plan.hw_grid), sh),
-        out_shardings=rep)
-
-    def grid_fn(idx, hw, gi):
-        idx = jax.device_put(jnp.asarray(idx, jnp.int32), sh)
-        gi = jax.device_put(jnp.asarray(gi, jnp.int32), sh)
-        hw = jax.tree.map(lambda x: jax.device_put(x, sh), hw)
-        return jitted(idx, hw, gi)
-    return grid_fn
+    return _shard_call(fn, images, mesh, reduce)
 
 
 def sweep(program: Union[Program, ProgramBatch, Sequence[Program], None]
@@ -609,7 +560,7 @@ def sweep(program: Union[Program, ProgramBatch, Sequence[Program], None]
 
     chunk_steps / blk_b / max_buckets default to ``autotune.AUTO``: they
     resolve through the per-shape-class autotune cache
-    (``core.autotune``), falling back to the static defaults (64 / 32 /
+    (``core.autotune``), falling back to the static defaults (64 / 128 /
     4) when the shape was never tuned.  Pass concrete values to pin
     knobs (``chunk_steps=None`` still means "disable chunking").  With
     ``autotune=True`` (or ``REPRO_AUTOTUNE=1``) an untuned multi-program
@@ -628,12 +579,12 @@ def sweep(program: Union[Program, ProgramBatch, Sequence[Program], None]
     row order and are bit-identical to the unbucketed sweep; compiled
     cores grow by at most the number of buckets, not G.
 
-    Mesh sharding works for both backends: the XLA scan path is pjit'ed
-    (GSPMD partitions the vmapped scan) while the Pallas engine runs SPMD
-    under ``shard_map`` -- each device sweeps its shard of the flat grid
-    through its own VMEM-resident engine with an independent early-exit
-    loop.  Results are identical on 1 and N devices; a grid that does not
-    divide the device count is padded with duplicate lanes and sliced back.
+    Mesh sharding works the same way for both backends (``_shard_call``):
+    under ``jax.shard_map`` each device sweeps its shard of the flat
+    grid through its own engine with an independent early-exit loop.
+    Results are identical on 1 and N devices and stay sharded over the
+    mesh; a grid that does not divide the device count is padded with
+    duplicate lanes and sliced back.
 
     The bank-scoreboard bound of the contention model is derived here from
     the configs (padded to a power of two); configs beyond the hard
@@ -792,9 +743,6 @@ def sweep(program: Union[Program, ProgramBatch, Sequence[Program], None]
     else:
         fn = make_sweep_fn(batch, profile, **kw, reduce=reduce)
 
-    def grid_fn(idx, hw, gi):
-        return fn(jnp.take(images, idx, axis=0), hw, gi)
-
     if mesh is None:
         if reduce is not None:
             lane_idx = jnp.arange(G * H * D, dtype=jnp.int32)
@@ -804,62 +752,34 @@ def sweep(program: Union[Program, ProgramBatch, Sequence[Program], None]
         if single_const:
             # legacy data flow: the constant-closure vfn is unjitted by
             # design (tables fold into the executable); jit the wrapper
-            return jax.jit(grid_fn)(img_idx, hw_grid, prog_idx)
+            return jax.jit(lambda idx, hw, gi: fn(
+                jnp.take(images, idx, axis=0), hw, gi))(
+                    img_idx, hw_grid, prog_idx)
         # operand core: already jitted + lru-cached, so call it eagerly
         # -- a per-call jit wrapper here would recompile the whole
         # pipeline every sweep() call and forfeit the steady state
         return fn(jnp.take(images, img_idx, axis=0), hw_grid, prog_idx)
 
-    from ..parallel.sharding import (batch_sharding, flat_batch_spec,
-                                     pad_batch, padded_len,
-                                     replicated_sharding)
-    # Both mesh paths need the flat grid divisible by the device count;
+    from ..parallel.sharding import pad_batch, padded_len
+    # The mesh path needs the flat grid divisible by the device count;
     # pad with duplicate (harmless, independent) lanes and slice back.
     B = G * H * D
     Bp = padded_len(B, int(mesh.devices.size))
     img_idx = pad_batch(img_idx, Bp)
     prog_idx = pad_batch(prog_idx, Bp)
     hw_grid = jax.tree.map(lambda x: pad_batch(x, Bp), hw_grid)
-
+    call = _shard_call(fn, images, mesh, reduce)
     if reduce is not None:
-        # SPMD reduce: every device reduces its shard on device and only
-        # the gathered n_devices*K candidate rows reach the host merge.
-        # The duplicate pad lanes are masked via lane_idx = -1.
+        # duplicate pad lanes are masked via lane_idx = -1
         lane_idx = pad_batch(jnp.arange(B, dtype=jnp.int32), Bp, fill=-1)
-        call = _reduced_shard_call(fn, images, mesh, reduce,
-                                   int(mesh.devices.size))
-        return call(img_idx, prog_idx, lane_idx, hw_grid)
-
-    if backend == "pallas":
-        # pallas_call does not partition under pjit/GSPMD; run the engine
-        # SPMD with shard_map over the flat (program x hw x data) axis.
-        # The images are replicated and gathered per-shard by index (the
-        # program tables ride inside fn as replicated operands), exactly
-        # as in the unsharded grid_fn.
-        from jax.sharding import PartitionSpec
-
-        def shard_fn(imgs, idx, gi, hw):
-            return fn(jnp.take(imgs, idx, axis=0), hw, gi)
-
-        sharded = jax.jit(_shard_map(
-            shard_fn, mesh,
-            in_specs=(PartitionSpec(), flat_batch_spec(mesh),
-                      flat_batch_spec(mesh), flat_batch_spec(mesh)),
-            out_specs=flat_batch_spec(mesh)))
-        res = sharded(images, img_idx, prog_idx, hw_grid)
-    else:
-        sh = batch_sharding(mesh)
-        rep = replicated_sharding(mesh)
-        img_idx = jax.device_put(img_idx, sh)
-        prog_idx = jax.device_put(prog_idx, sh)
-        # every hw_grid leaf is 1-D by construction (stack_configs + tile)
-        hw_grid = jax.tree.map(lambda x: jax.device_put(x, sh), hw_grid)
-        grid_fn = jax.jit(
-            grid_fn,
-            in_shardings=(sh, jax.tree.map(lambda _: sh, hw_grid), sh),
-            out_shardings=rep)
-        res = grid_fn(img_idx, hw_grid, prog_idx)
-    return jax.tree.map(lambda x: x[:B], res)
+        return call(img_idx, hw_grid, prog_idx, lane_idx)
+    res = call(img_idx, hw_grid, prog_idx)
+    if Bp == B:
+        return res                     # stays sharded over the mesh
+    # a padded grid no longer splits evenly: replicate, then slice
+    from ..parallel.sharding import replicated_sharding
+    rep = replicated_sharding(mesh)
+    return jax.tree.map(lambda x: jax.device_put(x, rep)[:B], res)
 
 
 def make_bucketed_sweep_fn(programs, profile: Profile,

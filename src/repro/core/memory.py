@@ -35,7 +35,7 @@ from .hwconfig import BUS_N_TO_M, HwConfig
 # clipped bank indices >= 16 in gather and dropped them in scatter --
 # i.e. *wrong contention results with no error*).
 DEFAULT_MAX_BANKS = 16   # bound used when no configs are in scope yet
-HARD_MAX_BANKS = 256     # absolute ceiling (VMEM scoreboard budget)
+HARD_MAX_BANKS = 256     # absolute ceiling (scoreboard size per lane)
 
 # Backwards-compatible alias for pre-lift callers.
 MAX_BANKS = DEFAULT_MAX_BANKS
@@ -52,8 +52,8 @@ def scoreboard_bound(n_banks_required: int) -> int:
         raise AssertionError(
             f"n_banks={n} exceeds HARD_MAX_BANKS={HARD_MAX_BANKS}: the "
             f"bank scoreboard would need {n} slots per design point; "
-            f"raise HARD_MAX_BANKS deliberately (VMEM cost: "
-            f"4*blk_b*{n} bytes/tile) or reduce the configured bank count")
+            f"raise HARD_MAX_BANKS deliberately or reduce the configured "
+            f"bank count")
     return 1 << (n - 1).bit_length()
 
 

@@ -1,48 +1,50 @@
 """Fused multi-step CGRA sweep engine: the Pallas kernel.
 
-One kernel invocation advances a (blk_b,)-lane tile of independent design
-points by K CGRA instructions, with every piece of architectural state --
-registers (blk_b, 4, P), output registers (blk_b, P), per-lane PC / done /
-cycle counter / executed-step counter / case-(vi) energy accumulator, and
-the full (blk_b, M) scratchpad memory image -- resident in VMEM for the
-whole chunk.  The fused program row table (G*T_max, N_ROW_FIELDS, P) --
-all G kernels of the sweep, every per-instruction field stacked into one
-array -- is read from HBM once per tile instead of once per instruction,
-which is the entire point: the XLA scan path re-reads state every step,
-while here HBM traffic is amortized K-fold.
+Lane-major layout.  Design points sit on the 128-wide lane axis and the
+architectural state of a lane tile is a set of ``(rows, L)`` VMEM blocks:
+the scratchpad memory ``(M, L)``, the register file ``(4*P, L)``, the
+output registers ``(P, L)``, the control words ``(N_CTL, L)`` (PC, done,
+cycle counter, previous PC, executed-step counter) and the case-(vi)
+energy accumulator ``(1, L)``.  One kernel invocation advances the tile
+by K CGRA instructions with all of it resident in VMEM, so HBM traffic
+is amortized K-fold over the XLA scan path, which re-reads state every
+step.  Every per-lane lookup is written in a form Mosaic lowers:
 
-The *program axis is data*: each lane carries a program index, and the
-whole instruction is fetched with ONE scalar-prefetch-style gather of the
-fused row table (``program.fused_rows``, ``(G*T_max, N_ROW_FIELDS, P)``)
-at row ``prog_idx * T_max + pc`` -- the ten per-field gathers of the
-original engine collapsed into a single row fetch.  The row for the NEXT
-instruction is double-buffered: each step ends by prefetching the row at
-the just-resolved PC, so the fetch of step k+1 overlaps the (much wider)
-execute data flow of step k instead of serializing in front of it.  The
-previous instruction's switch-energy reference rows ride in the loop
-carry (refreshed from the persisted ``prev_pc`` once per chunk), so no
-step ever re-gathers them.  Per-lane true program lengths clip the PC,
-so NOP padding beyond a short kernel's end is never executed
-(bit-identical to sweeping that kernel alone).
+  * instruction fetch: the fused program table (``program.fused_rows``,
+    all G kernels of the sweep) is stored as bf16 byte planes
+    ``(N_FETCH * P, R)`` -- every field fits a byte except the
+    immediate, which is split into four.  A lane's whole instruction is
+    ONE one-hot matmul ``planes @ onehot(prog_idx * T_max + pc)`` on the
+    MXU; a one-hot column sums exactly one byte, so the f32 result is
+    exact.  The fetch of step k+1 is issued at the end of step k
+    (double-buffered row), and the previous instruction's switch-energy
+    reference rows ride in the loop carry;
+  * memory: loads gather and stores scatter through 8-word row blocks
+    of the ``(M, L)`` image -- one dynamic sublane slice per distinct
+    block the tile touches, with an 8-way select inside it.  Stores are
+    applied in ascending PE order, so the last writer wins (the
+    simulator's store arbitration) without a pairwise dedup;
+  * small tables (per-opcode powers, source-kind energies) are SMEM
+    scalars selected by compare, never gathered;
+  * the bank/DMA contention model is the ascending-PE greedy list
+    scheduler of core/memory.py in closed form: a request's issue slot
+    is one past the latest earlier request that shares its bank or its
+    DMA engine, so no per-lane scoreboard is indexed.  Bit-identical to
+    the scoreboard formulation because slots on one resource strictly
+    increase in PE order.
 
-Fused per step, entirely on the VPU (no MXU use -- int32 lane math):
-  * per-lane (program, PC) gather of the instruction row
-    (op/dest/srcA/srcB/imm),
-  * operand-source gather (immediates, register file, own/neighbour ROUT),
-  * branchless ALU dispatch over the full ISA (shared with the
-    kernels/cgra_step single-instruction kernel: alu_select),
-  * scratchpad load/store with last-writer-wins store arbitration,
-  * the bank/DMA pipelined-issue contention model (ascending-PE greedy
-    list scheduler, bit-identical to core/memory.py),
-  * lockstep retire timing and branch resolution,
-  * the case-(vi) energy estimate (decode + active + idle + operand-source
-    + datapath-switch terms, mirroring core/dse.py's fused estimate).
+Fused per step, on the VPU (the MXU only fetches): operand selection
+(immediates, register file, own/neighbour ROUT -- torus neighbours are
+static row permutations), branchless ALU dispatch over the full ISA
+(``alu_select``, shared with kernels/cgra_step), the memory access,
+the contention model, lockstep retire timing, branch resolution (lowest
+taken PE wins) and the case-(vi) energy estimate (decode + active +
+idle + operand-source + datapath-switch terms, mirroring core/dse.py).
 
-Lanes that have executed EXIT (or exhausted the `max_steps` budget
-mid-chunk) are frozen by masking, so a chunk is always safe to overshoot;
-the host-side driver (ops.py) stops issuing chunks once every lane
-reports done -- the early-exit that makes short kernels stop paying for
-max_steps.
+Lanes that have executed EXIT (or exhausted the ``max_steps`` budget
+mid-chunk) are frozen by masking, so a chunk is always safe to
+overshoot; the host-side loop in ops.py stops issuing chunks once every
+lane reports done.
 """
 from __future__ import annotations
 
@@ -50,266 +52,284 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
 
 from ...core import isa
 from ...core.hwconfig import BUS_N_TO_M
-from ...core.memory import DEFAULT_MAX_BANKS
-from ...core.program import ROW_IDX
+from ...core.program import ROW_FIELDS
 from ..cgra_step.kernel import alu_select
 
-# Column layout of the packed per-lane integer hardware descriptor.
+# Rows of the per-lane integer operand block (hardware descriptor plus the
+# lane's program row base and true program length).
 HW_INT_FIELDS = ("smul_lat", "bus", "interleaved", "n_banks",
                  "dma_per_pe", "t_mem")
+LANE_ROWS = HW_INT_FIELDS + ("row_base", "n_instrs")
+# Rows of the per-lane control block carried between chunks.
+CTL_ROWS = ("pc", "done", "t_cc", "prev_pc", "n_exec")
+N_CTL = len(CTL_ROWS)
+# Byte planes of the fetch table: one per fused-row field, four for imm.
+FETCH_PLANES = tuple(p for f in ROW_FIELDS
+                     for p in ((f"imm{i}" for i in range(4)) if f == "imm"
+                               else (f,)))
+N_FETCH = len(FETCH_PLANES)
+_PLANE = {p: i for i, p in enumerate(FETCH_PLANES)}
+
+
+def fetch_planes(fused: np.ndarray, n_rows_pad: int) -> np.ndarray:
+    """``(R, N_ROW_FIELDS, P)`` int32 fused rows -> ``(N_FETCH * P,
+    n_rows_pad)`` bf16 byte planes (row ``plane * P + pe``, column = table
+    row).  Every non-immediate field must fit in a byte."""
+    R, _, P = fused.shape
+    planes = []
+    for i, f in enumerate(ROW_FIELDS):
+        col = fused[:, i, :].astype(np.int64)
+        if f == "imm":
+            u = col & 0xFFFFFFFF
+            planes += [(u >> (8 * k)) & 0xFF for k in range(4)]
+        else:
+            if col.min(initial=0) < 0 or col.max(initial=0) > 0xFF:
+                raise ValueError(f"fused row field {f!r} does not fit a "
+                                 f"byte plane")
+            planes.append(col)
+    out = np.zeros((N_FETCH * P, n_rows_pad), np.float32)
+    out[:, :R] = np.stack(planes, axis=0).transpose(0, 2, 1).reshape(
+        N_FETCH * P, R)
+    return out.astype(jnp.bfloat16)
 
 
 def build_sweep_kernel(*, rows: int, cols: int, mem_size: int,
-                       n_instrs: int, k_steps: int, max_steps: int,
+                       n_rows: int, k_steps: int, max_steps: int,
                        p_idle: float, e_sw_op: float, e_sw_mux: float,
-                       mulzero: float, n_progs: int = 1,
-                       max_banks: int = DEFAULT_MAX_BANKS) -> Callable:
+                       mulzero: float) -> Callable:
     """Build the fused K-step kernel body (closed over all static config).
 
-    n_instrs is the padded per-program length T_max; the program arrives
-    as ONE fused row table (n_progs * T_max, N_ROW_FIELDS, P) and each
-    lane's single per-step row fetch is based at its program index (see
-    module docstring).
-
-    max_banks: static bank-scoreboard width, config-derived by the driver
-    (memory.scoreboard_bound); a power of two so the VMEM tile stays
-    aligned."""
+    n_rows is the number of real rows of the fused table (``G * T_max``);
+    row indices are clipped into it, so a padded lane can never fetch
+    past the table.  mem_size is the simulated memory size (address
+    modulus and bank split); the ``(Mp, L)`` VMEM image is its 8-word
+    aligned height, whose padding rows no address reaches."""
     P = rows * cols
-    T = n_instrs
     M = mem_size
-    # Torus neighbour reads are grid rotations: gathering rout by the
-    # neighbour index map equals jnp.roll on the (rows, cols) view, which
-    # lowers to static slices -- no captured index constants in the kernel.
-    NBR_ROLL = {"RCL": (1, 2), "RCR": (-1, 2), "RCT": (1, 1), "RCB": (-1, 1)}
+    Mp = -(-M // 8) * 8
+    NBR = {name: tuple(int(i) for i in perm) for name, perm in
+           isa.neighbour_index_maps(rows, cols).items()}
     OP_SMUL = isa.OP["SMUL"]
     OP_EXIT = isa.OP["EXIT"]
     OP_LWD, OP_SWD = isa.OP["LWD"], isa.OP["SWD"]
     OP_BEQ, OP_BNE = isa.OP["BEQ"], isa.OP["BNE"]
     OP_BLT, OP_BGE, OP_JUMP = isa.OP["BLT"], isa.OP["BGE"], isa.OP["JUMP"]
+    BIG = np.int32(Mp)         # block index past the end: "no request"
 
-    def _operands(sel, imm_row, regs, rout):
-        """(blk, P) source selectors -> (blk, P) operand values."""
-        blk = sel.shape[0]
-        rout_grid = rout.reshape(blk, rows, cols)
-        val = jnp.zeros_like(imm_row)
-        val = jnp.where(sel == isa.SRC["IMM"], imm_row, val)
-        for r in range(4):
-            val = jnp.where(sel == isa.SRC[f"R{r}"], regs[:, r, :], val)
-        val = jnp.where(sel == isa.SRC["ROUT"], rout, val)
-        for name, (shift, axis) in NBR_ROLL.items():
-            nbr_val = jnp.roll(rout_grid, shift, axis=axis).reshape(blk, P)
-            val = jnp.where(sel == isa.SRC[name], nbr_val, val)
-        return val
+    def lut(idx, ref, n):
+        """Per-element ``ref[idx]`` for a small SMEM table."""
+        out = jnp.zeros(idx.shape, jnp.float32)
+        for k in range(n):
+            out = jnp.where(idx == k, ref[k], out)
+        return out
 
-    def _dedup(is_store, addr):
-        """Last-writer-wins store arbitration, lane-batched.  P is tiny
-        (16), so the P x P pairwise compare stays in registers -- the
-        sort-based O(P log P) form lives in core/cgra.py for the scan
-        path."""
-        i_row = jax.lax.broadcasted_iota(jnp.int32, (1, P, P), 1)
-        j_col = jax.lax.broadcasted_iota(jnp.int32, (1, P, P), 2)
-        later = (is_store[:, None, :]
-                 & (addr[:, None, :] == addr[:, :, None])
-                 & (j_col > i_row))
-        return is_store & ~later.any(axis=2)
-
-    def _mem_completion(is_mem, addr, bus, interleaved, n_banks,
-                        dma_per_pe, t_mem):
-        """Lane-batched pipelined-issue contention model; ascending-PE
-        greedy list scheduler, bit-identical to core/memory.py."""
+    def kernel(start_ref, pdec_ref, pact_ref, esrc_ref, tab_ref, lane_ref,
+               hwf_ref, mem_ref, regs_ref, rout_ref, ctl_ref, eacc_ref,
+               omem_ref, oregs_ref, orout_ref, octl_ref, oeacc_ref):
+        L = mem_ref.shape[1]
+        start = start_ref[0]
+        lanes = lane_ref[...]
+        smul_lat, bus, interleaved, n_banks, dma_per_pe, t_mem, base, \
+            lane_len = (lanes[i:i + 1] for i in range(len(LANE_ROWS)))
+        smul_scale = hwf_ref[...]
+        tab = tab_ref[...]
+        R = tab.shape[1]
+        pe = jax.lax.broadcasted_iota(jnp.int32, (P, L), 0)
+        sub8 = jax.lax.broadcasted_iota(jnp.int32, (8, L), 0)
+        # contention-model constants (lane-invariant per chunk)
         nb = jnp.maximum(n_banks, 1)
         bank_words = jnp.maximum(M // nb, 1)
-        interleave_bank = addr % nb[:, None]
-        blocked_bank = jnp.clip(addr // bank_words[:, None], 0,
-                                (n_banks - 1)[:, None])
-        bank = jnp.where(interleaved[:, None] > 0, interleave_bank,
-                         blocked_bank)
-        bank = jnp.where(bus[:, None] == BUS_N_TO_M, bank, 0)
-        pe = jax.lax.broadcasted_iota(jnp.int32, (1, P), 1)
-        dma = jnp.where(dma_per_pe[:, None] > 0, pe, pe % cols)
-        blk = is_mem.shape[0]
-        bank_free = jnp.zeros((blk, max_banks), jnp.int32)
-        dma_free = jnp.zeros((blk, P), jnp.int32)
-        bank_ids = jax.lax.broadcasted_iota(jnp.int32, (1, max_banks), 1)
-        dma_ids = jax.lax.broadcasted_iota(jnp.int32, (1, P), 1)
-        done_cols = []
-        for p in range(P):
-            req = is_mem[:, p]
-            b = bank[:, p]
-            d = dma[:, p]
-            bf = jnp.take_along_axis(bank_free, b[:, None], axis=1)[:, 0]
-            df = jnp.take_along_axis(dma_free, d[:, None], axis=1)[:, 0]
-            slot = jnp.maximum(bf, df)
-            hit_b = (bank_ids == b[:, None]) & req[:, None]
-            bank_free = jnp.where(hit_b, (slot + 1)[:, None], bank_free)
-            hit_d = (dma_ids == d[:, None]) & req[:, None]
-            dma_free = jnp.where(hit_d, (slot + 1)[:, None], dma_free)
-            done_cols.append(jnp.where(req, slot + t_mem, 0))
-        return jnp.stack(done_cols, axis=1).astype(jnp.int32)
-
-    # fused-row field indices (program.ROW_FIELDS layout)
-    F_OPS, F_DEST = ROW_IDX["ops"], ROW_IDX["dest"]
-    F_SRCA, F_SRCB = ROW_IDX["srcA"], ROW_IDX["srcB"]
-    F_IMM, F_ISLD = ROW_IDX["imm"], ROW_IDX["is_load"]
-    F_ISST, F_WR = ROW_IDX["is_store"], ROW_IDX["writes_rout"]
-    F_KA, F_KB = ROW_IDX["kindA"], ROW_IDX["kindB"]
-
-    def kernel(start_ref, plen_ref, tab_ref,
-               pdec_ref, pact_ref, esrc_ref, hwi_ref, hwf_ref, gidx_ref,
-               mem_ref, regs_ref, rout_ref, pc_ref, done_ref, tcc_ref,
-               eacc_ref, prev_ref, nexec_ref,
-               omem_ref, oregs_ref, orout_ref, opc_ref, odone_ref,
-               otcc_ref, oeacc_ref, oprev_ref, onexec_ref):
-        start = start_ref[0]
-        tab = tab_ref[...]                     # (G*T, N_ROW_FIELDS, P)
-        p_dec = pdec_ref[...]
-        p_act = pact_ref[...]
-        e_src = esrc_ref[...]
-        hw_i = hwi_ref[...]
-        smul_lat = hw_i[:, 0]
-        bus = hw_i[:, 1]
-        interleaved = hw_i[:, 2]
-        n_banks = hw_i[:, 3]
-        dma_per_pe = hw_i[:, 4]
-        t_mem = hw_i[:, 5]
-        smul_scale = hwf_ref[...]
-        # per-lane program: THE row fetch is based at gi * T in the fused
-        # (G*T, NF, P) table; the PC clips to this lane's true program
-        # length so padding never executes
-        gi = gidx_ref[...]
-        plen = plen_ref[...]
-        base = gi * T
-        lane_len = jnp.take(plen, gi, mode="clip")
-        blk = smul_lat.shape[0]
-        lane_rows = jax.lax.broadcasted_iota(jnp.int32, (blk, 1), 0)
+        dma = jnp.where(dma_per_pe > 0, pe, pe % cols)
+        omem_ref[...] = mem_ref[...]
 
         def fetch(row):
-            """(blk,) per-lane row index -> (blk, NF, P) fused rows: the
-            single gather that replaces the ten per-field gathers."""
-            return jnp.take(tab, row, axis=0, mode="clip")
+            """(1, L) table row per lane -> dict of (P, L) int32 fields."""
+            row = jnp.clip(row, 0, n_rows - 1)
+            onehot = (jax.lax.broadcasted_iota(jnp.int32, (R, L), 0)
+                      == row).astype(jnp.bfloat16)
+            f = jnp.dot(tab, onehot, preferred_element_type=jnp.float32
+                        ).astype(jnp.int32)
+            plane = lambda name: f[_PLANE[name] * P:(_PLANE[name] + 1) * P]
+            out = {n: plane(n) for n in ROW_FIELDS if n != "imm"}
+            out["imm"] = (plane("imm0") | (plane("imm1") << 8)
+                          | (plane("imm2") << 16) | (plane("imm3") << 24))
+            return out
+
+        def permute_rows(x, perm):
+            return jnp.concatenate([x[i:i + 1] for i in perm], axis=0)
+
+        def operands(sel, imm, regs, rout, nbrs):
+            val = jnp.zeros_like(imm)
+            val = jnp.where(sel == isa.SRC["IMM"], imm, val)
+            for r in range(4):
+                val = jnp.where(sel == isa.SRC[f"R{r}"],
+                                regs[r * P:(r + 1) * P], val)
+            val = jnp.where(sel == isa.SRC["ROUT"], rout, val)
+            for name, nv in nbrs.items():
+                val = jnp.where(sel == isa.SRC[name], nv, val)
+            return val
+
+        def next_block(need, blk):
+            """Smallest 8-word block any pending (PE, lane) request needs,
+            and that block's rows."""
+            b = jnp.min(jnp.where(need > 0, blk, BIG))
+            start_row = pl.multiple_of(b * 8, 8)
+            return b, omem_ref[pl.ds(start_row, 8), :], start_row
+
+        def load(addr, need0):
+            """(P, L) words at addr (old memory) where need0 > 0, else 0."""
+            blk, sub = addr >> 3, addr & 7
+
+            def body(c):
+                need, val = c
+                b, chunk, _ = next_block(need, blk)
+                hit = (need > 0) & (blk == b)
+                got = jnp.zeros_like(val)
+                for s in range(8):
+                    got = jnp.where(sub == s, chunk[s:s + 1], got)
+                return (jnp.where(hit, 0, need), jnp.where(hit, got, val))
+
+            _, val = jax.lax.while_loop(
+                lambda c: jnp.max(c[0]) > 0, body,
+                (need0, jnp.zeros_like(addr)))
+            return val
+
+        def store(addr, val, need0):
+            """mem[addr] = val where need0 > 0, ascending PE order."""
+            blk, sub = addr >> 3, addr & 7
+
+            def body(need):
+                b, chunk, start_row = next_block(need, blk)
+                hit = (need > 0) & (blk == b)
+                for p in range(P):
+                    chunk = jnp.where(
+                        hit[p:p + 1] & (sub8 == sub[p:p + 1]),
+                        val[p:p + 1], chunk)
+                omem_ref[pl.ds(start_row, 8), :] = chunk
+                return jnp.where(hit, 0, need)
+
+            jax.lax.while_loop(lambda need: jnp.max(need) > 0, body, need0)
+
+        def mem_completion(is_mem, addr):
+            """Pipelined-issue contention: slot(p) = 1 + the latest slot of
+            an earlier request on the same bank or DMA engine (0 if none);
+            completion = slot + t_mem (core/memory.py, closed form)."""
+            bank = jnp.where(interleaved > 0, addr % nb,
+                             jnp.clip(addr // bank_words, 0, n_banks - 1))
+            bank = jnp.where(bus == BUS_N_TO_M, bank, 0)
+            slot = jnp.zeros_like(addr)
+            for p in range(1, P):
+                clash = (is_mem & (pe < p)
+                         & ((bank == bank[p:p + 1]) | (dma == dma[p:p + 1])))
+                s = jnp.max(jnp.where(clash, slot + 1, 0), axis=0,
+                            keepdims=True)
+                slot = jnp.where(pe == p, s, slot)
+            return jnp.where(is_mem, slot + t_mem, 0)
 
         def step(k, carry):
-            (mem, regs, rout, pc, done, t_cc, e_acc, prev_pc, n_exec,
-             cur, has_prev, p_ops, p_srcA, p_srcB) = carry
-            budget_ok = start + k < max_steps
-            live = (done == 0) & budget_ok                    # (blk,)
-            op_row = cur[:, F_OPS, :]                         # (blk, P)
-            imm_row = cur[:, F_IMM, :]
-            srcA_row = cur[:, F_SRCA, :]
-            srcB_row = cur[:, F_SRCB, :]
-            a = _operands(srcA_row, imm_row, regs, rout)
-            b = _operands(srcB_row, imm_row, regs, rout)
+            (regs, rout, pc, done, t_cc, e_acc, prev_pc, n_exec, cur,
+             has_prev, p_ops, p_srcA, p_srcB) = carry
+            live = (done == 0) & (start + k < max_steps)       # (1, L)
+            op = cur["ops"]
+            imm = cur["imm"]
+            srcA, srcB = cur["srcA"], cur["srcB"]
+            nbrs = {n: permute_rows(rout, perm) for n, perm in NBR.items()}
+            a = operands(srcA, imm, regs, rout, nbrs)
+            b = operands(srcB, imm, regs, rout, nbrs)
 
-            # ---- memory --------------------------------------------------
-            is_load = cur[:, F_ISLD, :] > 0
-            is_store = cur[:, F_ISST, :] > 0
-            direct = (op_row == OP_LWD) | (op_row == OP_SWD)
-            addr = jnp.where(direct, imm_row, a) % M
-            load_val = jnp.take_along_axis(mem, addr, axis=1)
-            store_val = jnp.where(op_row == OP_SWD, a, b)
-            landed = _dedup(is_store, addr) & live[:, None]
-            mem = mem.at[lane_rows, jnp.where(landed, addr, M)].set(
-                jnp.where(landed, store_val, 0), mode="drop")
+            # ---- memory: every load sees the memory before the stores --
+            is_load = cur["is_load"] > 0
+            is_store = cur["is_store"] > 0
+            direct = (op == OP_LWD) | (op == OP_SWD)
+            addr = jnp.where(direct, imm, a) % M
+            load_val = load(addr, (is_load & live).astype(jnp.int32))
+            store(addr, jnp.where(op == OP_SWD, a, b),
+                  (is_store & live).astype(jnp.int32))
 
-            # ---- ALU + writeback -----------------------------------------
-            alu = alu_select(op_row, a, b)
-            result = jnp.where(is_load, load_val, alu)
-            writes = cur[:, F_WR, :] > 0
+            # ---- ALU + writeback ----------------------------------------
+            result = jnp.where(is_load, load_val, alu_select(op, a, b))
+            writes = cur["writes_rout"] > 0
             rout_new = jnp.where(writes, result, rout)
-            d_row = cur[:, F_DEST, :]
-            regs_new = jnp.stack(
-                [jnp.where(writes & (d_row == r), result, regs[:, r, :])
-                 for r in range(4)], axis=1)
+            dest = cur["dest"]
+            regs_new = jnp.concatenate(
+                [jnp.where(writes & (dest == r), result,
+                           regs[r * P:(r + 1) * P]) for r in range(4)],
+                axis=0)
 
-            # ---- timing --------------------------------------------------
-            is_mem_row = is_load | is_store
-            mem_done = _mem_completion(is_mem_row, addr, bus, interleaved,
-                                       n_banks, dma_per_pe, t_mem)
-            alu_lat = jnp.where(op_row == OP_SMUL, smul_lat[:, None], 1)
-            busy = jnp.where(is_mem_row, mem_done, alu_lat).astype(jnp.int32)
-            lat = busy.max(axis=1)
+            # ---- timing -------------------------------------------------
+            is_mem = is_load | is_store
+            alu_lat = jnp.where(op == OP_SMUL, smul_lat, 1)
+            busy = jnp.where(is_mem, mem_completion(is_mem, addr), alu_lat)
+            lat = jnp.max(busy, axis=0, keepdims=True)
 
-            # ---- control -------------------------------------------------
-            taken = (((op_row == OP_BEQ) & (a == b))
-                     | ((op_row == OP_BNE) & (a != b))
-                     | ((op_row == OP_BLT) & (a < b))
-                     | ((op_row == OP_BGE) & (a >= b))
-                     | (op_row == OP_JUMP))
-            any_taken = taken.any(axis=1)
-            first = jnp.argmax(taken, axis=1)     # lowest PE wins
-            target = jnp.take_along_axis(imm_row, first[:, None],
-                                         axis=1)[:, 0]
-            next_pc = jnp.clip(jnp.where(any_taken, target, pc + 1),
-                               0, lane_len - 1).astype(jnp.int32)
-            exited = (op_row == OP_EXIT).any(axis=1)
+            # ---- control: the lowest-indexed taken branch wins ----------
+            taken = (((op == OP_BEQ) & (a == b)) | ((op == OP_BNE) & (a != b))
+                     | ((op == OP_BLT) & (a < b))
+                     | ((op == OP_BGE) & (a >= b)) | (op == OP_JUMP))
+            first = jnp.min(jnp.where(taken, pe, P), axis=0, keepdims=True)
+            target = jnp.sum(jnp.where(pe == first, imm, 0), axis=0,
+                             keepdims=True)
+            next_pc = jnp.clip(jnp.where(first < P, target, pc + 1),
+                               0, lane_len - 1)
+            exited = jnp.max(jnp.where(op == OP_EXIT, 1, 0), axis=0,
+                             keepdims=True) > 0
 
-            # ---- fused case-(vi) energy (mirrors core/dse.py) ------------
-            smul = op_row == OP_SMUL
-            scale = jnp.where(smul, smul_scale[:, None], 1.0)
-            wait = jnp.maximum(lat[:, None] - busy, 0).astype(jnp.float32)
+            # ---- fused case-(vi) energy (mirrors core/dse.py) -----------
+            smul = op == OP_SMUL
+            scale = jnp.where(smul, smul_scale, 1.0)
+            wait = jnp.maximum(lat - busy, 0).astype(jnp.float32)
             active = jnp.maximum(busy - 1, 0).astype(jnp.float32)
             gate = jnp.where(smul & ((a == 0) | (b == 0)), mulzero, 1.0)
-            prev_ok = has_prev[:, None]
-            op_ch = prev_ok & (op_row != p_ops)
-            a_ch = prev_ok & (srcA_row != p_srcA)
-            b_ch = prev_ok & (srcB_row != p_srcB)
-            e_step = (p_dec[op_row] * scale
-                      + p_act[op_row] * scale * gate * active
+            prev_ok = has_prev > 0
+            op_ch = (prev_ok & (op != p_ops)).astype(jnp.float32)
+            a_ch = prev_ok & (srcA != p_srcA)
+            b_ch = prev_ok & (srcB != p_srcB)
+            e_step = (lut(op, pdec_ref, isa.N_OPS) * scale
+                      + lut(op, pact_ref, isa.N_OPS) * scale * gate * active
                       + p_idle * wait
-                      + e_src[cur[:, F_KA, :]]
-                      + e_src[cur[:, F_KB, :]]
+                      + lut(cur["kindA"], esrc_ref, isa.N_SRC_KINDS)
+                      + lut(cur["kindB"], esrc_ref, isa.N_SRC_KINDS)
                       + op_ch * e_sw_op
                       + (a_ch.astype(jnp.float32)
                          + b_ch.astype(jnp.float32)) * e_sw_mux
-                      ).sum(axis=1)
+                      ).sum(axis=0, keepdims=True)
 
-            # ---- live-masked state advance -------------------------------
-            lv = live[:, None]
+            # ---- live-masked state advance ------------------------------
             new_pc = jnp.where(live, next_pc, pc)
-            # double buffer: prefetch the row the NEXT iteration executes,
-            # so the (narrow) fetch overlaps this step's execute data flow
-            new_cur = fetch(base + new_pc)
-            return (mem,                       # stores already live-masked
-                    jnp.where(lv[:, :, None], regs_new, regs),
-                    jnp.where(lv, rout_new, rout),
+            return (jnp.where(live, regs_new, regs),
+                    jnp.where(live, rout_new, rout),
                     new_pc,
-                    jnp.where(live & exited, 1, done).astype(jnp.int32),
+                    jnp.where(live & exited, 1, done),
                     jnp.where(live, t_cc + lat, t_cc),
                     e_acc + jnp.where(live, e_step, 0.0),
                     jnp.where(live, pc, prev_pc),
                     jnp.where(live, n_exec + 1, n_exec),
-                    new_cur,
-                    has_prev | live,
-                    jnp.where(lv, op_row, p_ops),
-                    jnp.where(lv, srcA_row, p_srcA),
-                    jnp.where(lv, srcB_row, p_srcB))
+                    fetch(base + new_pc),      # the row the next step runs
+                    jnp.where(live, 1, has_prev),
+                    jnp.where(live, op, p_ops),
+                    jnp.where(live, srcA, p_srcA),
+                    jnp.where(live, srcB, p_srcB))
 
-        pc0 = pc_ref[...]
-        prev_pc0 = prev_ref[...]
-        # seed the double buffer + the carried switch-energy reference rows
-        # (re-fetched once per CHUNK from the persisted prev_pc, vs once
-        # per STEP in the original engine)
-        cur0 = fetch(base + pc0)
-        pfr = fetch(base + jnp.maximum(prev_pc0, 0))
-        carry = (mem_ref[...], regs_ref[...], rout_ref[...], pc0,
-                 done_ref[...], tcc_ref[...], eacc_ref[...], prev_pc0,
-                 nexec_ref[...],
-                 cur0, prev_pc0 >= 0,
-                 pfr[:, F_OPS, :], pfr[:, F_SRCA, :], pfr[:, F_SRCB, :])
-        carry = jax.lax.fori_loop(0, k_steps, step, carry)
-        (mem, regs, rout, pc, done, t_cc, e_acc, prev_pc, n_exec,
-         _, _, _, _, _) = carry
-        omem_ref[...] = mem
+        ctl = ctl_ref[...]
+        pc0, done0, tcc0, prev0, nexec0 = (ctl[i:i + 1] for i in range(N_CTL))
+        # seed the double buffer and the carried switch-energy reference
+        # rows (fetched once per chunk from the persisted prev_pc)
+        pfr = fetch(base + jnp.maximum(prev0, 0))
+        carry = (regs_ref[...], rout_ref[...], pc0, done0, tcc0,
+                 eacc_ref[...], prev0, nexec0, fetch(base + pc0),
+                 (prev0 >= 0).astype(jnp.int32),
+                 pfr["ops"], pfr["srcA"], pfr["srcB"])
+        (regs, rout, pc, done, t_cc, e_acc, prev_pc, n_exec,
+         *_) = jax.lax.fori_loop(0, k_steps, step, carry)
         oregs_ref[...] = regs
         orout_ref[...] = rout
-        opc_ref[...] = pc
-        odone_ref[...] = done
-        otcc_ref[...] = t_cc
+        octl_ref[...] = jnp.concatenate([pc, done, t_cc, prev_pc, n_exec],
+                                        axis=0)
         oeacc_ref[...] = e_acc
-        oprev_ref[...] = prev_pc
-        onexec_ref[...] = n_exec
 
     return kernel

@@ -6,9 +6,11 @@ bit-identical latency, checksum and executed-step counts, energy equal
 to float32 accumulation order.  Given a single ``Program`` it returns
 ``fn(mem_init (B, M), hw batched (B,))``; given a program sequence or a
 ``ProgramBatch`` it returns ``fn(mem_init, hw, prog_idx)`` and each lane
-fetches its kernel's instructions -- one fused-row gather per step --
-from the fused (G*T_max, N_ROW_FIELDS, P) table inside the kernel: the
-program axis is swept as data, through one compiled engine.
+fetches its kernel's instructions -- one one-hot fetch per step -- from
+the fused table of all G kernels inside the kernel: the program axis is
+swept as data, through one compiled engine.  ``_fn`` transposes the
+batch to the kernel's lane-major layout (design points on the 128-wide
+lane axis) and back.
 
 The program tables, per-program lengths and profile vectors are
 *operands* of an lru-cached jitted core (one per static configuration),
@@ -33,20 +35,24 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ...core import isa
+from ...core.autotune import PALLAS_BLK_B
 from ...core.characterization import Profile
 from ...core.hwconfig import HwConfig
 from ...core.memory import DEFAULT_MAX_BANKS, validate_bank_bound
-from ...core.program import (N_ROW_FIELDS, Program, as_program_batch,
-                             batch_tables, fused_rows)
-from .kernel import HW_INT_FIELDS, build_sweep_kernel
+from ...core.program import (Program, as_program_batch, batch_tables,
+                             fused_rows)
+from .kernel import (HW_INT_FIELDS, LANE_ROWS, N_CTL, build_sweep_kernel,
+                     fetch_planes)
+
+LANE_WIDTH = 128     # TPU vreg lane count: the compiled lane-tile quantum
 
 
 @functools.lru_cache(maxsize=None)
 def _pallas_sweep_core(rows: int, cols: int, mem_size: int, t_max: int,
                        n_progs: int, k_steps: int, max_steps: int,
-                       max_banks: int, blk_b: int, interpret: bool,
+                       blk_b: int, interpret: bool,
                        p_idle: float, e_sw_op: float, e_sw_mux: float,
                        mulzero: float, t_clk: float):
     """One jitted Pallas sweep core per static configuration; program
@@ -55,35 +61,38 @@ def _pallas_sweep_core(rows: int, cols: int, mem_size: int, t_max: int,
 
     P = rows * cols
     T = t_max
-    G = n_progs
     M = mem_size
+    Mp = -(-M // 8) * 8                  # memory height in 8-word blocks
     K = k_steps
 
     kern = build_sweep_kernel(
-        rows=rows, cols=cols, mem_size=M, n_instrs=T, k_steps=K,
-        max_steps=max_steps, max_banks=max_banks, n_progs=G,
-        p_idle=p_idle, e_sw_op=e_sw_op, e_sw_mux=e_sw_mux, mulzero=mulzero)
+        rows=rows, cols=cols, mem_size=M, n_rows=n_progs * T, k_steps=K,
+        max_steps=max_steps, p_idle=p_idle, e_sw_op=e_sw_op,
+        e_sw_mux=e_sw_mux, mulzero=mulzero)
 
-    def _chunk_call(Bp, start, tab, plen, prof, hw_i, hw_f, gidx,
-                    mem, regs, rout, pc, done, t_cc, e_acc, prev, n_exec):
-        grid = (Bp // blk_b,)
-        bcast = lambda shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
-        lane1 = pl.BlockSpec((blk_b,), lambda i: (i,))
-        lane = lambda *rest: pl.BlockSpec((blk_b,) + rest,
-                                          lambda i: (i,) + (0,) * len(rest))
-        state_specs = [lane(M), lane(4, P), lane(P), lane1, lane1, lane1,
-                       lane1, lane1, lane1]
-        in_specs = ([bcast((1,)), bcast((G,)),
-                     bcast((G * T, N_ROW_FIELDS, P))]
-                    + [bcast((isa.N_OPS,))] * 2 + [bcast((isa.N_SRC_KINDS,))]
-                    + [lane(len(HW_INT_FIELDS)), lane1, lane1] + state_specs)
-        out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in
-                     (mem, regs, rout, pc, done, t_cc, e_acc, prev, n_exec)]
+    def _chunk_call(tile, start, tab, prof, lanes, hw_f, state):
+        Bp = lanes.shape[1]
+        smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+        lane = lambda n: pl.BlockSpec((n, tile), lambda i: (0, i))
+        state_specs = [lane(Mp), lane(4 * P), lane(P), lane(N_CTL),
+                       lane(1)]
+        in_specs = ([smem] * 4
+                     + [pl.BlockSpec(tab.shape, lambda i: (0, 0)),
+                        lane(len(LANE_ROWS)), lane(1)] + state_specs)
+        # in + out state blocks are double-buffered by the pipeline
+        state_bytes = 4 * tile * (Mp + 5 * P + N_CTL + 1)
+        vmem = 4 * state_bytes + 4 * tab.size + (16 << 20)
         return pl.pallas_call(
-            kern, grid=grid, in_specs=in_specs, out_specs=state_specs,
-            out_shape=out_shape, interpret=interpret,
-        )(start, plen, tab, *prof, hw_i, hw_f, gidx,
-          mem, regs, rout, pc, done, t_cc, e_acc, prev, n_exec)
+            kern, grid=(Bp // tile,), in_specs=in_specs,
+            out_specs=state_specs,
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                       for x in state],
+            input_output_aliases={7 + i: i for i in range(len(state))},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",),
+                vmem_limit_bytes=int(vmem)),
+            interpret=interpret,
+        )(start, *prof, tab, lanes, hw_f, *state)
 
     @jax.jit
     def _fn(tab, plen, prof, mem_init: jnp.ndarray, hw: HwConfig,
@@ -91,55 +100,59 @@ def _pallas_sweep_core(rows: int, cols: int, mem_size: int, t_max: int,
         TRACE_COUNTS["pallas"] += 1       # trace-time only: retrace probe
         mem0 = jnp.asarray(mem_init, jnp.int32)
         B = mem0.shape[0]
-        Bp = -(-B // blk_b) * blk_b
+        # one lane tile when the batch fits it (a block equal to the whole
+        # array is legal at any width), else blk_b-wide tiles
+        tile = min(blk_b, -(-B // 8) * 8)
+        Bp = -(-B // tile) * tile
         pad = Bp - B
 
-        def padb(x, fill=0):
-            widths = ((0, pad),) + ((0, 0),) * (x.ndim - 1)
-            return jnp.pad(x, widths, constant_values=fill)
+        def lanes_of(x, fill=0):
+            """(B,) per-lane values -> (1, Bp) lane row."""
+            x = jnp.asarray(x).reshape(1, B)
+            return jnp.pad(x, ((0, 0), (0, pad)), constant_values=fill)
 
-        hw_i = padb(jnp.stack(
-            [jnp.asarray(getattr(hw, f)).astype(jnp.int32).reshape(B)
-             for f in HW_INT_FIELDS], axis=1), fill=1)
-        hw_f = padb(jnp.asarray(hw.smul_power_scale,
-                                jnp.float32).reshape(B), fill=1)
-        gidx = padb(jnp.asarray(prog_idx, jnp.int32).reshape(B))
+        gi = jnp.asarray(prog_idx, jnp.int32).reshape(B)
+        lanes = jnp.concatenate(
+            [lanes_of(jnp.asarray(getattr(hw, f)).astype(jnp.int32), 1)
+             for f in HW_INT_FIELDS]
+            + [lanes_of(gi * T), lanes_of(jnp.take(plen, gi), 1)], axis=0)
+        hw_f = lanes_of(jnp.asarray(hw.smul_power_scale, jnp.float32), 1)
         state = (
-            padb(mem0),                                       # mem
-            jnp.zeros((Bp, 4, P), jnp.int32),                 # regs
-            jnp.zeros((Bp, P), jnp.int32),                    # rout
-            jnp.zeros((Bp,), jnp.int32),                      # pc
-            padb(jnp.zeros((B,), jnp.int32), fill=1),         # done (pad=1)
-            jnp.zeros((Bp,), jnp.int32),                      # t_cc
-            jnp.zeros((Bp,), jnp.float32),                    # e_acc
-            jnp.full((Bp,), -1, jnp.int32),                   # prev_pc
-            jnp.zeros((Bp,), jnp.int32),                      # n_exec
+            jnp.pad(mem0.T, ((0, Mp - M), (0, pad))),          # mem
+            jnp.zeros((4 * P, Bp), jnp.int32),                  # regs
+            jnp.zeros((P, Bp), jnp.int32),                      # rout
+            jnp.concatenate([                                   # ctl
+                jnp.zeros((1, Bp), jnp.int32),                  #   pc
+                lanes_of(jnp.zeros((B,), jnp.int32), 1),        #   done
+                jnp.zeros((1, Bp), jnp.int32),                  #   t_cc
+                jnp.full((1, Bp), -1, jnp.int32),               #   prev_pc
+                jnp.zeros((1, Bp), jnp.int32)], axis=0),        #   n_exec
+            jnp.zeros((1, Bp), jnp.float32),                    # e_acc
         )
 
         def cond(c):
             t0, st = c
-            return (t0 < max_steps) & (jnp.min(st[4]) == 0)
+            return (t0 < max_steps) & (jnp.min(st[3][1]) == 0)
 
         def body(c):
             t0, st = c
             start = jnp.full((1,), t0, jnp.int32)
-            st = _chunk_call(Bp, start, tab, plen, prof, hw_i, hw_f, gidx,
-                             *st)
+            st = _chunk_call(tile, start, tab, prof, lanes, hw_f, st)
             return (t0 + K, tuple(st))
 
         _, st = jax.lax.while_loop(cond, body, (jnp.int32(0), state))
-        mem, _, _, _, _, t_cc, e_acc, _, n_exec = st
-        lat_cc = t_cc[:B]
-        e_uwcc = e_acc[:B]
+        mem, _, _, ctl, e_acc = st
+        lat_cc = ctl[2, :B]
+        e_uwcc = e_acc[0, :B]
         # clock period comes from the characterization profile, exactly as
         # in the XLA backend and the trace estimator (hw.t_clk_ns is not
         # consulted by either)
-        energy_pj = e_uwcc * jnp.float32(t_clk) * 1e-3
+        energy_pj = e_uwcc * (jnp.float32(t_clk) * 1e-3)
         power_mw = e_uwcc / jnp.maximum(lat_cc, 1) * 1e-3
-        weights = (jnp.arange(M, dtype=jnp.int32) | 1)[None, :]
-        checksum = (mem[:B] * weights).sum(axis=1).astype(jnp.int32)
+        weights = (jnp.arange(M, dtype=jnp.int32) | 1)[:, None]
+        checksum = (mem[:M, :B] * weights).sum(axis=0).astype(jnp.int32)
         return SweepResult(lat_cc, energy_pj, power_mw, checksum,
-                           n_exec[:B])
+                           ctl[4, :B])
 
     return _fn
 
@@ -169,7 +182,7 @@ def make_pallas_sweep_fn(program, profile: Profile, *,
                          rows: int = 4, cols: int = 4, mem_size: int = 4096,
                          max_steps: int = 2048,
                          chunk_steps: Optional[int] = 64,
-                         blk_b: int = 32,
+                         blk_b: int = PALLAS_BLK_B,
                          interpret: Optional[bool] = None,
                          max_banks: int = DEFAULT_MAX_BANKS,
                          validate: bool = True,
@@ -183,7 +196,11 @@ def make_pallas_sweep_fn(program, profile: Profile, *,
     ``ParetoFront``).  When given, the batch API becomes ``fn(mem, hw,
     prog_idx, lane_idx) -> ReducedResult`` with the per-program
     reduction fused into the same compiled program as the sweep engine
-    (the full ``(B,)`` grid never leaves the device)."""
+    (the full ``(B,)`` grid never leaves the device).
+
+    blk_b: design points per lane tile.  Compiled for the TPU it must be
+    a multiple of the 128-lane vreg width; a batch that fits one tile
+    runs as a single tile of its own width."""
     single = isinstance(program, Program)
     batch = as_program_batch(program)
     tables = batch_tables(batch)
@@ -197,19 +214,23 @@ def make_pallas_sweep_fn(program, profile: Profile, *,
     K = max(1, min(chunk_steps or max_steps, max_steps))
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if not interpret and (blk_b < 1 or blk_b % LANE_WIDTH):
+        raise ValueError(
+            f"blk_b={blk_b}: a compiled Pallas lane tile must be a "
+            f"positive multiple of {LANE_WIDTH}")
 
-    # The fused row table (G*T, N_ROW_FIELDS, P): one HBM read per tile,
-    # every lane fetches its whole instruction with ONE gather of row
-    # prog_idx * T + pc (see kernel.py docstring).
-    tab = jnp.asarray(fused_rows(tables))
+    # The fused row table as bf16 byte planes (N_FETCH * P, G*T padded to
+    # the lane width): every lane fetches its whole instruction with ONE
+    # one-hot matmul at row prog_idx * T + pc (see kernel.py docstring).
+    tab = jnp.asarray(fetch_planes(
+        fused_rows(tables), -(-G * T // LANE_WIDTH) * LANE_WIDTH))
     plen = jnp.asarray(batch.n_instrs, jnp.int32)          # (G,)
     prof = (jnp.asarray(profile.p_dec, jnp.float32),
             jnp.asarray(profile.p_act, jnp.float32),
             jnp.asarray(profile.e_src, jnp.float32))
 
     core = _pallas_sweep_core(
-        rows, cols, mem_size, T, G, K, max_steps, max_banks, blk_b,
-        bool(interpret),
+        rows, cols, mem_size, T, G, K, max_steps, blk_b, bool(interpret),
         float(np.asarray(profile.p_idle)),
         float(np.asarray(profile.e_sw_op)),
         float(np.asarray(profile.e_sw_mux)),

@@ -193,8 +193,8 @@ def constrain(x, *logical: Optional[str]):
 # ---------------------------------------------------------------------------
 # Flat-batch sweep sharding: the DSE (program x hw x data) grid is one
 # long batch axis spread over EVERY axis of whatever mesh the caller
-# brings ((data,), (pod, data, model), ...).  Shared by the pjit'ed XLA
-# sweep path and the shard_map'ed Pallas sweep path (core/dse.py): the
+# brings ((data,), (pod, data, model), ...).  Used by the one shard_map
+# sweep path of both backends (core/dse.py ``_shard_call``): the
 # per-lane index vectors (img_idx, prog_idx) and the stacked HwConfig
 # leaves all shard with flat_batch_spec, while the gathered-by-index
 # payloads (memory images, packed program tables) stay replicated.
@@ -208,11 +208,6 @@ def flat_batch_spec(mesh: Mesh) -> P:
 def padded_len(n: int, n_devices: int) -> int:
     """Smallest multiple of ``n_devices`` >= n (flat-grid pad target)."""
     return -(-n // n_devices) * n_devices
-
-
-def batch_sharding(mesh: Mesh) -> NamedSharding:
-    """NamedSharding for a flat batch axis over the whole mesh."""
-    return NamedSharding(mesh, flat_batch_spec(mesh))
 
 
 def replicated_sharding(mesh: Mesh) -> NamedSharding:

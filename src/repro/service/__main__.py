@@ -9,6 +9,8 @@ import sys
 
 
 def main(argv=None):
+    from ..compile_cache import enable_compile_cache
+    enable_compile_cache()
     argv = sys.argv[1:] if argv is None else list(argv)
     if argv and argv[0] == "serve":
         from .transport import serve_main

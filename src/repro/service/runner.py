@@ -93,18 +93,28 @@ class BackendStage:
 def backend_chain(backend: str,
                   interpret: Optional[bool] = None
                   ) -> Tuple[BackendStage, ...]:
-    """Degradation chain for a requested backend: compiled Pallas ->
-    Pallas interpreter -> XLA scan.  (Requesting ``interpret=True``
-    starts the chain at the interpreter stage; ``xla`` has nowhere
-    slower-but-safer to go.)"""
+    """Degradation chain for a requested backend.
+
+    On a TPU: compiled Pallas -> XLA scan.  The Pallas interpreter never
+    stands in for the compiled kernel there: a unit that falls back is
+    recorded as degraded to ``xla``, and ``interpret=True`` is refused.
+    Off-TPU, where the engine only runs interpreted, the chain is
+    ``pallas`` (auto-interpreted) -> ``pallas_interpret`` -> ``xla``;
+    ``interpret=True`` starts it at the interpreter stage.  ``xla`` has
+    nowhere slower-but-safer to go."""
     if backend == "xla":
         return (BackendStage("xla", "xla", None),)
     if backend != "pallas":
         raise ValueError(f"unknown sweep backend: {backend!r}")
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu and interpret:
+        raise ValueError("interpret=True: the Pallas interpreter is not a "
+                         "TPU sweep backend")
     stages = []
     if interpret is not True:
         stages.append(BackendStage("pallas", "pallas", interpret))
-    stages.append(BackendStage("pallas_interpret", "pallas", True))
+    if not on_tpu:
+        stages.append(BackendStage("pallas_interpret", "pallas", True))
     stages.append(BackendStage("xla", "xla", None))
     return tuple(stages)
 

@@ -181,7 +181,11 @@ class SweepTransport:
     and step serialize on ``_lock`` -- jax tracing is not thread-safe,
     and the service was written single-threaded); HTTP handler threads
     only do JSON/base64 and blocking waits on campaign conditions, so
-    streams stay live (heartbeats included) while a unit computes.
+    streams stay live (heartbeats included) while a unit computes.  The
+    campaign registry has its own short-held ``_reg`` lock (taken after
+    ``_lock`` when both are needed): a status or stream lookup never
+    waits for the worker, which re-takes ``_lock`` back to back while
+    it computes.
     """
 
     def __init__(self, service: SweepService, host: str = "127.0.0.1",
@@ -195,7 +199,8 @@ class SweepTransport:
         # campaign 404s; the client re-submits under its key)
         self.campaign_cap = max(1, int(campaign_cap))
         self.poll_s = poll_s
-        self._lock = threading.Lock()        # service + registry
+        self._lock = threading.Lock()        # service
+        self._reg = threading.Lock()         # campaign registry
         self._campaigns: "OrderedDict[str, _Campaign]" = OrderedDict()
         self._by_key: Dict[str, str] = {}
         self._by_rid: Dict[int, str] = {}
@@ -254,10 +259,12 @@ class SweepTransport:
     def _sync_completed(self):
         """Move finished service results into campaign terminal state
         (under ``_lock``)."""
-        for rid in [r for r in self.service.completed
-                    if r in self._by_rid]:
+        with self._reg:
+            done = [(rid, self._campaigns.get(self._by_rid.pop(rid)))
+                    for rid in list(self.service.completed)
+                    if rid in self._by_rid]
+        for rid, camp in done:
             res = self.service.completed.pop(rid)
-            camp = self._campaigns.get(self._by_rid.pop(rid))
             if camp is None:
                 continue
             camp.finish("complete", {
@@ -285,7 +292,9 @@ class SweepTransport:
                 runner: ResumableSweepRunner = slot.runner
                 if runner.mgr is not None:
                     runner.mgr.wait()
-            for camp in self._campaigns.values():
+            with self._reg:
+                camps = list(self._campaigns.values())
+            for camp in camps:
                 if camp.status in ("queued", "running"):
                     camp.finish("drained", {})
 
@@ -301,9 +310,10 @@ class SweepTransport:
             raise ValueError(
                 f"wire version {body.get('v')!r} != {WIRE_VERSION}")
         with self._lock:
-            cid = self._by_key.get(key)
-            if cid is not None and cid in self._campaigns:
-                return cid, False, 200
+            with self._reg:
+                cid = self._by_key.get(key)
+                if cid is not None and cid in self._campaigns:
+                    return cid, False, 200
             kw = sweep_from_wire(body["sweep"])
             reduced = kw["reduce"] is not None
             req = SweepRequest(**kw)
@@ -313,14 +323,17 @@ class SweepTransport:
                 lambda rid, lo, hi, arrays: camp.push(lo, hi, arrays)
             rid = self.service.submit(req)   # may raise ServiceOverloaded
             camp.rid = rid
-            self._campaigns[cid] = camp
-            self._by_key[key] = cid
-            self._by_rid[rid] = cid
-            self._evict_finished()
+            with self._reg:
+                self._campaigns[cid] = camp
+                self._by_key[key] = cid
+                self._by_rid[rid] = cid
+                self._evict_finished()
             self._work.set()
             return cid, True, 201
 
     def _evict_finished(self):
+        """Drop the oldest finished campaigns past the cap (under
+        ``_reg``)."""
         done = [c for c in self._campaigns.values()
                 if c.status in ("complete", "drained")]
         excess = len(self._campaigns) - self.campaign_cap
@@ -330,7 +343,7 @@ class SweepTransport:
                 self._by_key.pop(camp.key, None)
 
     def campaign(self, cid: str) -> Optional[_Campaign]:
-        with self._lock:
+        with self._reg:
             return self._campaigns.get(cid)
 
 

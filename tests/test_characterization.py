@@ -62,3 +62,25 @@ def test_characterize_is_deterministic(profile):
     again = characterize()
     np.testing.assert_allclose(profile.p_dec, again.p_dec, rtol=1e-6)
     np.testing.assert_array_equal(profile.lat, again.lat)
+
+
+def test_default_profile_serves_what_the_committed_code_builds(
+        monkeypatch, tmp_path, profile):
+    """No profile file outlives the code that built it: after a change to
+    the characterization, ``default_profile`` serves the new fit, and it
+    reads and writes no file on the way (here: none appears in a fresh
+    working directory or TMPDIR)."""
+    import dataclasses
+
+    from repro.core import characterization as ch
+    changed = dataclasses.replace(profile, p_idle=profile.p_idle * 2.0)
+    monkeypatch.setattr(ch, "characterize", lambda: changed)
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setenv("TMPDIR", str(tmp_path))
+    ch.default_profile.cache_clear()
+    try:
+        assert ch.default_profile() is changed
+        assert ch.default_profile() is changed       # once per process
+    finally:
+        ch.default_profile.cache_clear()
+    assert list(tmp_path.iterdir()) == []

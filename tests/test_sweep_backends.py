@@ -7,7 +7,7 @@ Three independent implementations of simulate+estimate must agree:
   * the trace-based numpy estimator (core/estimator.py case (vi)).
 Latency, checksum and steps_executed must be bit-identical; energy equal
 to float32 accumulation order.  Early-exit chunking and mesh sharding
-(shard_map for pallas, pjit for xla) must be invisible in results.
+(one shard_map path for both backends) must be invisible in results.
 """
 import os
 import subprocess
@@ -93,8 +93,8 @@ def _hw_batch():
     return hws
 
 
-def _run_backend(program, mem_images, hws, backend, **kw):
-    fn = dse.make_sweep_fn(program, kw.pop("profile"), mem_size=MEM,
+def _run_backend(program, mem_images, hws, backend, mem_size=MEM, **kw):
+    fn = dse.make_sweep_fn(program, kw.pop("profile"), mem_size=mem_size,
                            max_steps=MAX_STEPS, backend=backend, **kw)
     B = len(hws)
     mems = jnp.asarray(np.broadcast_to(
@@ -113,6 +113,25 @@ def test_pallas_matches_xla_random_programs(seed, profile):
     np.testing.assert_array_equal(rx.checksum, rp.checksum)
     np.testing.assert_allclose(rx.energy_pj, rp.energy_pj, rtol=1e-5)
     np.testing.assert_allclose(rx.power_mw, rp.power_mw, rtol=1e-5)
+
+
+@pytest.mark.parametrize("mem_size", [100, 250])
+def test_pallas_matches_xla_unaligned_mem_size(mem_size, profile):
+    """A memory size that is not a multiple of the 8-word VMEM row block:
+    addresses wrap at mem_size and blocked banks split mem_size, not the
+    padded height."""
+    program, mem = _random_program(0)
+    hws = _hw_batch() + [
+        HwConfig(bus=1, interleaved=0, n_banks=nb, dma_per_pe=1, t_mem=2)
+        for nb in (3, 4, 8)]
+    mem = mem[:mem_size]
+    rx = _run_backend(program, mem, hws, "xla", mem_size, profile=profile)
+    rp = _run_backend(program, mem, hws, "pallas", mem_size, profile=profile,
+                      blk_b=4, interpret=True)
+    np.testing.assert_array_equal(rx.latency_cc, rp.latency_cc)
+    np.testing.assert_array_equal(rx.checksum, rp.checksum)
+    np.testing.assert_array_equal(rx.steps_executed, rp.steps_executed)
+    np.testing.assert_allclose(rx.energy_pj, rp.energy_pj, rtol=1e-5)
 
 
 def test_pallas_matches_xla_loop_kernel(profile):
@@ -327,3 +346,24 @@ def test_sweep_grid_ordering(backend, profile):
     np.testing.assert_array_equal(chk[0], chk[1])
     # different images -> different checksums
     assert (chk[:, 0] != chk[:, 1]).all()
+
+
+def test_compiled_pallas_tiles_are_lane_aligned(profile):
+    """Mosaic takes lane tiles in multiples of 128: the default and every
+    autotune candidate is one, and a compiled engine refuses any other
+    tile up front (interpret mode takes any tile)."""
+    from repro.core.autotune import (ShapeClass, default_blk_b,
+                                     default_cache, default_candidates)
+    shape = ShapeClass(G=2, t_max=8, H=4, D=2, backend="pallas")
+    tiles = {default_blk_b("pallas")} | {
+        c["blk_b"] for c in default_candidates(shape, 256)}
+    assert all(t % 128 == 0 for t in tiles), tiles
+    # an untuned shape resolves to the backend's own tile
+    fresh = ShapeClass(G=3, t_max=9, H=5, D=7, backend="pallas")
+    assert default_cache().resolve(fresh).blk_b % 128 == 0
+    prog, _ = _random_program(3)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        dse.make_sweep_fn([prog], profile, mem_size=MEM, backend="pallas",
+                          interpret=False, blk_b=32)
+    dse.make_sweep_fn([prog], profile, mem_size=MEM, backend="pallas",
+                      interpret=True, blk_b=32)

@@ -169,6 +169,18 @@ def test_degradation_chain_order():
     assert [s.name for s in backend_chain("xla")] == ["xla"]
 
 
+def test_degradation_chain_on_tpu_never_interprets(monkeypatch):
+    """On a TPU the interpreter must not hide a failing compiled kernel:
+    the chain is compiled Pallas -> XLA, and interpret=True is refused."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert [s.name for s in backend_chain("pallas")] == ["pallas", "xla"]
+    assert [s.name for s in backend_chain("pallas", interpret=False)] \
+        == ["pallas", "xla"]
+    with pytest.raises(ValueError, match="interpreter"):
+        backend_chain("pallas", interpret=True)
+
+
 def test_persistent_backend_failure_degrades_to_xla(grid, mono):
     """Both Pallas rungs broken -> every unit lands on the XLA rung,
     recorded in report.degraded, and the discrete outputs still match

@@ -19,6 +19,7 @@ import subprocess
 import sys
 import threading
 import time
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -317,7 +318,7 @@ def _serve(port_file, ckpt_root, backend, port=0):
          "--unit-size", "1", "--max-steps", str(MAX_STEPS),
          "--mem-size", str(DRILL_MEM), "--backend", backend,
          "--ckpt-root", str(ckpt_root)],
-        cwd="/root/repo", env=env,
+        cwd=str(Path(__file__).resolve().parents[1]), env=env,
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
 
 
