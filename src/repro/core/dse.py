@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import isa
+from .. import obs
 from ..analysis import pareto as _pareto
 from .autotune import (AUTO, ShapeClass, autotune_enabled, default_blk_b,
                        default_cache, is_auto, tune_sweep)
@@ -414,25 +415,26 @@ def plan_grid(program: Union[Program, ProgramBatch, Sequence[Program], None]
     (row ``(g*H + h)*D + d``) without materializing any tiled images or
     tables.  ``sweep()`` consumes the whole plan in one call; the sweep
     service slices it into checkpointable work units."""
-    if programs is not None:
-        if program is not None:
-            raise TypeError("plan_grid(): pass either program or "
-                            "programs=, not both")
-        program = list(programs)
-    batch = as_program_batch(program)
-    G = batch.n_programs
-    H, D = len(hw_configs), mem_images.shape[0]
-    n_banks_req = max(int(np.asarray(c.n_banks)) for c in hw_configs)
-    max_banks = scoreboard_bound(max(n_banks_req, DEFAULT_MAX_BANKS))
-    hw_b = stack_configs(list(hw_configs))
-    # broadcast to the full flat grid: hw h repeats over the data axis,
-    # then the (hw x data) block tiles over the program axis
-    hw_grid = jax.tree.map(
-        lambda x: jnp.tile(jnp.repeat(x, D, axis=0), G), hw_b)
-    images = jnp.asarray(mem_images, jnp.int32)          # (D, M), one copy
-    img_idx = np.tile(np.arange(D, dtype=np.int32), G * H)      # (G*H*D,)
-    prog_idx = np.repeat(np.arange(G, dtype=np.int32), H * D)
-    return GridPlan(batch, images, img_idx, prog_idx, hw_grid, max_banks)
+    with obs.span("dse.plan"):
+        if programs is not None:
+            if program is not None:
+                raise TypeError("plan_grid(): pass either program or "
+                                "programs=, not both")
+            program = list(programs)
+        batch = as_program_batch(program)
+        G = batch.n_programs
+        H, D = len(hw_configs), mem_images.shape[0]
+        n_banks_req = max(int(np.asarray(c.n_banks)) for c in hw_configs)
+        max_banks = scoreboard_bound(max(n_banks_req, DEFAULT_MAX_BANKS))
+        hw_b = stack_configs(list(hw_configs))
+        # broadcast to the full flat grid: hw h repeats over the data axis,
+        # then the (hw x data) block tiles over the program axis
+        hw_grid = jax.tree.map(
+            lambda x: jnp.tile(jnp.repeat(x, D, axis=0), G), hw_b)
+        images = jnp.asarray(mem_images, jnp.int32)          # (D, M), one copy
+        img_idx = np.tile(np.arange(D, dtype=np.int32), G * H)      # (G*H*D,)
+        prog_idx = np.repeat(np.arange(G, dtype=np.int32), H * D)
+        return GridPlan(batch, images, img_idx, prog_idx, hw_grid, max_banks)
 
 
 def _shard_call(fn, images, mesh, reduce=None):
@@ -465,14 +467,19 @@ def _shard_call(fn, images, mesh, reduce=None):
         out_specs=flat, check_vma=False))
 
     def call(idx, hw, gi, *lane):
-        out = sharded(images, jnp.asarray(idx, jnp.int32), jnp.asarray(
-            gi, jnp.int32), hw, *(jnp.asarray(x, jnp.int32) for x in lane))
+        with obs.span("dse.dispatch"):
+            out = sharded(images, jnp.asarray(idx, jnp.int32), jnp.asarray(
+                gi, jnp.int32), hw, *(jnp.asarray(x, jnp.int32)
+                                      for x in lane))
         if reduce is None:
             return out
-        stacked = [np.asarray(leaf) for leaf in out]
-        parts = [_pareto.ReducedResult(*(leaf[i] for leaf in stacked))
-                 for i in range(n_dev)]
-        return _pareto.merge_reduced(reduce, parts)
+        with obs.span("dse.wait"):
+            out = jax.block_until_ready(out)
+        with obs.span("dse.merge"):
+            stacked = [np.asarray(leaf) for leaf in out]
+            parts = [_pareto.ReducedResult(*(leaf[i] for leaf in stacked))
+                     for i in range(n_dev)]
+            return _pareto.merge_reduced(reduce, parts)
 
     call.sharded = sharded        # the SPMD program, for AOT compilation
     return call
@@ -620,166 +627,186 @@ def sweep(program: Union[Program, ProgramBatch, Sequence[Program], None]
     winning mapping id is ``mappings.mapping_of[idx // (H*D)]``.  Pass
     ``fold_mappings=False`` to keep per-candidate reduced rows.
     """
-    if mappings is not None:
-        if program is not None or programs is not None:
-            raise TypeError(
-                "sweep: pass mappings= OR program(s)=, not both")
-        res = sweep(programs=list(mappings.programs), profile=profile,
-                    hw_configs=hw_configs, mem_images=mem_images,
-                    mesh=mesh, max_steps=max_steps, mem_size=mem_size,
-                    backend=backend, chunk_steps=chunk_steps, blk_b=blk_b,
-                    max_buckets=max_buckets, autotune=autotune,
-                    interpret=interpret, reduce=reduce,
-                    observed_steps=observed_steps)
-        if reduce is not None and fold_mappings:
-            return _pareto.fold_segments(reduce, res, mappings.kernel_of,
-                                         mappings.n_kernels)
-        return res
-    plan = plan_grid(program, hw_configs, mem_images, programs=programs)
-    batch = plan.batch
-    G = batch.n_programs
-    H, D = len(hw_configs), mem_images.shape[0]
-    n_dev = int(mesh.devices.size) if mesh is not None else 1
+    with obs.span("dse.sweep", H=len(hw_configs),
+                  D=int(mem_images.shape[0])) as sp:
+        if mappings is not None:
+            if program is not None or programs is not None:
+                raise TypeError(
+                    "sweep: pass mappings= OR program(s)=, not both")
+            res = sweep(programs=list(mappings.programs), profile=profile,
+                        hw_configs=hw_configs, mem_images=mem_images,
+                        mesh=mesh, max_steps=max_steps, mem_size=mem_size,
+                        backend=backend, chunk_steps=chunk_steps, blk_b=blk_b,
+                        max_buckets=max_buckets, autotune=autotune,
+                        interpret=interpret, reduce=reduce,
+                        observed_steps=observed_steps)
+            if reduce is not None and fold_mappings:
+                return _pareto.fold_segments(reduce, res, mappings.kernel_of,
+                                             mappings.n_kernels)
+            return res
+        plan = plan_grid(program, hw_configs, mem_images, programs=programs)
+        batch = plan.batch
+        G = batch.n_programs
+        sp.set_metadata(G=G)
+        H, D = len(hw_configs), mem_images.shape[0]
+        n_dev = int(mesh.devices.size) if mesh is not None else 1
 
-    cache = default_cache()
-    if is_auto(backend):
-        # backend itself is a tuned knob: explicit > cached winner >
-        # (with tuning opted in) time xla-vs-pallas now > default xla
-        auto_shape = ShapeClass(G=G, t_max=batch.t_max, H=H, D=D,
-                                backend=AUTO, n_devices=n_dev)
-        cached_b = cache.lookup(auto_shape)
-        if cached_b is not None and cached_b.backend in ("xla", "pallas"):
-            backend = cached_b.backend
-        elif autotune_enabled(autotune) and G > 1:
-            cfg_b = tune_sweep(batch, profile, hw_configs, mem_images,
-                               backend=AUTO, max_steps=max_steps,
-                               mem_size=mem_size, mesh=mesh,
-                               interpret=interpret, cache=cache)
-            backend = cfg_b.backend or "xla"
-        else:
-            backend = "xla"
+        cache = default_cache()
+        if is_auto(backend):
+            # backend itself is a tuned knob: explicit > cached winner >
+            # (with tuning opted in) time xla-vs-pallas now > default xla
+            auto_shape = ShapeClass(G=G, t_max=batch.t_max, H=H, D=D,
+                                    backend=AUTO, n_devices=n_dev)
+            cached_b = cache.lookup(auto_shape)
+            if cached_b is not None and cached_b.backend in ("xla", "pallas"):
+                backend = cached_b.backend
+            elif autotune_enabled(autotune) and G > 1:
+                cfg_b = tune_sweep(batch, profile, hw_configs, mem_images,
+                                   backend=AUTO, max_steps=max_steps,
+                                   mem_size=mem_size, mesh=mesh,
+                                   interpret=interpret, cache=cache)
+                backend = cfg_b.backend or "xla"
+            else:
+                backend = "xla"
 
-    shape = ShapeClass(G=G, t_max=batch.t_max, H=H, D=D, backend=backend,
-                       n_devices=n_dev)
-    cfg = cache.resolve(shape, blk_b=blk_b, chunk_steps=chunk_steps,
-                        max_buckets=max_buckets)
-    if (autotune_enabled(autotune) and cfg.source == "default" and G > 1
-            and is_auto(blk_b, chunk_steps, max_buckets)):
-        # first encounter of an untuned shape with tuning opted in: time
-        # the candidate grid once, persist, and run with the winner
-        cfg = tune_sweep(batch, profile, hw_configs, mem_images,
-                         backend=backend, max_steps=max_steps,
-                         mem_size=mem_size, mesh=mesh, interpret=interpret,
-                         cache=cache)
+        shape = ShapeClass(G=G, t_max=batch.t_max, H=H, D=D, backend=backend,
+                           n_devices=n_dev)
+        cfg = cache.resolve(shape, blk_b=blk_b, chunk_steps=chunk_steps,
+                            max_buckets=max_buckets)
+        if (autotune_enabled(autotune) and cfg.source == "default" and G > 1
+                and is_auto(blk_b, chunk_steps, max_buckets)):
+            # first encounter of an untuned shape with tuning opted in: time
+            # the candidate grid once, persist, and run with the winner
+            cfg = tune_sweep(batch, profile, hw_configs, mem_images,
+                             backend=backend, max_steps=max_steps,
+                             mem_size=mem_size, mesh=mesh, interpret=interpret,
+                             cache=cache)
 
-    if G > 1 and cfg.max_buckets > 1:
-        buckets = bucket_programs([batch.program(g) for g in range(G)],
-                                  cfg.max_buckets,
-                                  observed_steps=observed_steps)
-        if buckets.n_buckets > 1:
-            block = H * D
-            # Forward the caller's original chunk/blk knobs (AUTO or
-            # explicit), not the resolved top-level values: each bucket
-            # is its own shape class (G=n_b, its own t_max), so an AUTO
-            # knob picks up that bucket's tuned winner -- a short-kernel
-            # bucket can run a smaller chunk_steps than a long one.
-            parts = [
-                sweep(program=b, profile=profile, hw_configs=hw_configs,
-                      mem_images=mem_images, mesh=mesh, max_steps=max_steps,
-                      mem_size=mem_size, backend=backend,
-                      chunk_steps=chunk_steps, blk_b=blk_b,
-                      max_buckets=1, autotune=False, interpret=interpret,
-                      reduce=reduce)
-                for b in buckets.batches]
+        if G > 1 and cfg.max_buckets > 1:
+            buckets = bucket_programs([batch.program(g) for g in range(G)],
+                                      cfg.max_buckets,
+                                      observed_steps=observed_steps)
+            if buckets.n_buckets > 1:
+                block = H * D
+                # Forward the caller's original chunk/blk knobs (AUTO or
+                # explicit), not the resolved top-level values: each bucket
+                # is its own shape class (G=n_b, its own t_max), so an AUTO
+                # knob picks up that bucket's tuned winner -- a short-kernel
+                # bucket can run a smaller chunk_steps than a long one.
+                parts = []
+                for bi, b in enumerate(buckets.batches):
+                    with obs.span("dse.bucket", bucket=bi):
+                        parts.append(sweep(
+                            program=b, profile=profile,
+                            hw_configs=hw_configs, mem_images=mem_images,
+                            mesh=mesh, max_steps=max_steps,
+                            mem_size=mem_size, backend=backend,
+                            chunk_steps=chunk_steps, blk_b=blk_b,
+                            max_buckets=1, autotune=False,
+                            interpret=interpret, reduce=reduce))
 
-            if reduce is not None:
-                # Each bucket reduced itself on device; lift its rows
-                # into the global segment space (bucket-local program j
-                # maps to canonical program g, shifting candidate flat
-                # indices by the row-block offset) and merge the K-sized
-                # candidate sets -- never B-sized grids -- on the host.
-                placed = [
-                    _pareto.remap_segments(
-                        part, buckets.groups[bi],
-                        [(g - j) * block
-                         for j, g in enumerate(buckets.groups[bi])], G)
-                    for bi, part in enumerate(parts)]
-                return _pareto.merge_reduced(reduce, placed)
+                if reduce is not None:
+                    # Each bucket reduced itself on device; lift its rows
+                    # into the global segment space (bucket-local program j
+                    # maps to canonical program g, shifting candidate flat
+                    # indices by the row-block offset) and merge the K-sized
+                    # candidate sets -- never B-sized grids -- on the host.
+                    with obs.span("dse.merge"):
+                        placed = [
+                            _pareto.remap_segments(
+                                part, buckets.groups[bi],
+                                [(g - j) * block
+                                 for j, g in enumerate(buckets.groups[bi])],
+                                G)
+                            for bi, part in enumerate(parts)]
+                        return _pareto.merge_reduced(reduce, placed)
 
-            def scatter(*leaves):
-                out = None
-                for bi, leaf in enumerate(leaves):
-                    a = np.asarray(leaf)
-                    if out is None:
-                        out = np.empty((G * block,) + a.shape[1:], a.dtype)
-                    for j, g in enumerate(buckets.groups[bi]):
-                        out[g * block:(g + 1) * block] = \
-                            a[j * block:(j + 1) * block]
-                return jnp.asarray(out)
+                def scatter(*leaves):
+                    out = None
+                    for bi, leaf in enumerate(leaves):
+                        a = np.asarray(leaf)
+                        if out is None:
+                            out = np.empty((G * block,) + a.shape[1:], a.dtype)
+                        for j, g in enumerate(buckets.groups[bi]):
+                            out[g * block:(g + 1) * block] = \
+                                a[j * block:(j + 1) * block]
+                    return jnp.asarray(out)
 
-            return jax.tree.map(scatter, *parts)
+                with obs.span("dse.merge"):
+                    return jax.tree.map(scatter, *parts)
 
-    images = plan.images
-    img_idx = jnp.asarray(plan.img_idx)
-    prog_idx = jnp.asarray(plan.prog_idx)
-    hw_grid = plan.hw_grid
-    # validate=False: every config was checked against the plan's derived
-    # scoreboard bound, so no runtime guard needs to be staged into the
-    # compiled sweep
-    kw = dict(max_steps=max_steps, mem_size=mem_size, backend=backend,
-              chunk_steps=cfg.chunk_steps, blk_b=cfg.blk_b,
-              interpret=interpret, max_banks=plan.max_banks, validate=False)
-    # The constant-closure fast path is reserved for callers that hand us
-    # a bare Program (the legacy single-kernel API).  A 1-element batch
-    # or list goes through the operand core instead, so single-program
-    # buckets of a bucketed sweep share the cached executables.  A
-    # reduced sweep always uses the operand core (the reducer keys its
-    # segments on the prog_idx operand).
-    single_const = (programs is None and isinstance(program, Program)
-                    and reduce is None)
-    if single_const:
-        fn1 = make_sweep_fn(program, profile, **kw)
-        fn = lambda mem, hw, gi: fn1(mem, hw)
-    else:
-        fn = make_sweep_fn(batch, profile, **kw, reduce=reduce)
-
-    if mesh is None:
-        if reduce is not None:
-            lane_idx = jnp.arange(G * H * D, dtype=jnp.int32)
-            red = fn(jnp.take(images, img_idx, axis=0), hw_grid, prog_idx,
-                     lane_idx)
-            return _pareto.merge_reduced(reduce, [red])
+        images = plan.images
+        img_idx = jnp.asarray(plan.img_idx)
+        prog_idx = jnp.asarray(plan.prog_idx)
+        hw_grid = plan.hw_grid
+        # validate=False: every config was checked against the plan's derived
+        # scoreboard bound, so no runtime guard needs to be staged into the
+        # compiled sweep
+        kw = dict(max_steps=max_steps, mem_size=mem_size, backend=backend,
+                  chunk_steps=cfg.chunk_steps, blk_b=cfg.blk_b,
+                  interpret=interpret, max_banks=plan.max_banks,
+                  validate=False)
+        # The constant-closure fast path is reserved for callers that hand us
+        # a bare Program (the legacy single-kernel API).  A 1-element batch
+        # or list goes through the operand core instead, so single-program
+        # buckets of a bucketed sweep share the cached executables.  A
+        # reduced sweep always uses the operand core (the reducer keys its
+        # segments on the prog_idx operand).
+        single_const = (programs is None and isinstance(program, Program)
+                        and reduce is None)
         if single_const:
-            # legacy data flow: the constant-closure vfn is unjitted by
-            # design (tables fold into the executable); jit the wrapper
-            return jax.jit(lambda idx, hw, gi: fn(
-                jnp.take(images, idx, axis=0), hw, gi))(
-                    img_idx, hw_grid, prog_idx)
-        # operand core: already jitted + lru-cached, so call it eagerly
-        # -- a per-call jit wrapper here would recompile the whole
-        # pipeline every sweep() call and forfeit the steady state
-        return fn(jnp.take(images, img_idx, axis=0), hw_grid, prog_idx)
+            fn1 = make_sweep_fn(program, profile, **kw)
+            fn = lambda mem, hw, gi: fn1(mem, hw)
+        else:
+            fn = make_sweep_fn(batch, profile, **kw, reduce=reduce)
 
-    from ..parallel.sharding import pad_batch, padded_len
-    # The mesh path needs the flat grid divisible by the device count;
-    # pad with duplicate (harmless, independent) lanes and slice back.
-    B = G * H * D
-    Bp = padded_len(B, int(mesh.devices.size))
-    img_idx = pad_batch(img_idx, Bp)
-    prog_idx = pad_batch(prog_idx, Bp)
-    hw_grid = jax.tree.map(lambda x: pad_batch(x, Bp), hw_grid)
-    call = _shard_call(fn, images, mesh, reduce)
-    if reduce is not None:
-        # duplicate pad lanes are masked via lane_idx = -1
-        lane_idx = pad_batch(jnp.arange(B, dtype=jnp.int32), Bp, fill=-1)
-        return call(img_idx, hw_grid, prog_idx, lane_idx)
-    res = call(img_idx, hw_grid, prog_idx)
-    if Bp == B:
-        return res                     # stays sharded over the mesh
-    # a padded grid no longer splits evenly: replicate, then slice
-    from ..parallel.sharding import replicated_sharding
-    rep = replicated_sharding(mesh)
-    return jax.tree.map(lambda x: jax.device_put(x, rep)[:B], res)
+        if mesh is None:
+            if reduce is not None:
+                lane_idx = jnp.arange(G * H * D, dtype=jnp.int32)
+                with obs.span("dse.dispatch"):
+                    red = fn(jnp.take(images, img_idx, axis=0), hw_grid,
+                             prog_idx, lane_idx)
+                # merge_reduced reads the candidates on the host: the
+                # sweep's wait for the device happens here
+                with obs.span("dse.wait"):
+                    red = jax.block_until_ready(red)
+                with obs.span("dse.merge"):
+                    return _pareto.merge_reduced(reduce, [red])
+            with obs.span("dse.dispatch"):
+                if single_const:
+                    # legacy data flow: the constant-closure vfn is
+                    # unjitted by design (tables fold into the
+                    # executable); jit the wrapper
+                    return jax.jit(lambda idx, hw, gi: fn(
+                        jnp.take(images, idx, axis=0), hw, gi))(
+                            img_idx, hw_grid, prog_idx)
+                # operand core: already jitted + lru-cached, so call it
+                # eagerly -- a per-call jit wrapper here would recompile
+                # the whole pipeline every sweep() call and forfeit the
+                # steady state
+                return fn(jnp.take(images, img_idx, axis=0), hw_grid,
+                          prog_idx)
+
+        from ..parallel.sharding import pad_batch, padded_len
+        # The mesh path needs the flat grid divisible by the device count;
+        # pad with duplicate (harmless, independent) lanes and slice back.
+        B = G * H * D
+        Bp = padded_len(B, int(mesh.devices.size))
+        img_idx = pad_batch(img_idx, Bp)
+        prog_idx = pad_batch(prog_idx, Bp)
+        hw_grid = jax.tree.map(lambda x: pad_batch(x, Bp), hw_grid)
+        call = _shard_call(fn, images, mesh, reduce)
+        if reduce is not None:
+            # duplicate pad lanes are masked via lane_idx = -1
+            lane_idx = pad_batch(jnp.arange(B, dtype=jnp.int32), Bp, fill=-1)
+            return call(img_idx, hw_grid, prog_idx, lane_idx)
+        res = call(img_idx, hw_grid, prog_idx)
+        if Bp == B:
+            return res                     # stays sharded over the mesh
+        # a padded grid no longer splits evenly: replicate, then slice
+        from ..parallel.sharding import replicated_sharding
+        rep = replicated_sharding(mesh)
+        return jax.tree.map(lambda x: jax.device_put(x, rep)[:B], res)
 
 
 def make_bucketed_sweep_fn(programs, profile: Profile,

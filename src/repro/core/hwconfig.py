@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
+
 # Bus types.
 BUS_ONE_TO_M = 0   # single memory port; all requests serialize globally
 BUS_N_TO_M = 1     # banked; requests to different banks proceed in parallel
@@ -122,10 +124,12 @@ TOPOLOGIES = {
 def stack_configs(configs) -> HwConfig:
     """Stack a list of HwConfig into one batched HwConfig (leading axis) for
     vmap-based design-space sweeps."""
-    leaves = [jnp.stack([jnp.asarray(getattr(c, f), jnp.float32)
-                         if f in ("smul_power_scale", "t_clk_ns")
-                         else jnp.asarray(getattr(c, f), jnp.int32)
-                         for c in configs]) for f in HwConfig.FIELDS]
+    obs.COUNTS["hwconfig.configs_stacked"] += len(configs)
+    with obs.span("hwconfig.stack", n=len(configs)):
+        leaves = [jnp.stack([jnp.asarray(getattr(c, f), jnp.float32)
+                             if f in ("smul_power_scale", "t_clk_ns")
+                             else jnp.asarray(getattr(c, f), jnp.int32)
+                             for c in configs]) for f in HwConfig.FIELDS]
     cfg = HwConfig.__new__(HwConfig)
     for f, v in zip(HwConfig.FIELDS, leaves):
         setattr(cfg, f, v)

@@ -83,7 +83,7 @@ def _pallas_sweep_core(rows: int, cols: int, mem_size: int, t_max: int,
         state_bytes = 4 * tile * (Mp + 5 * P + N_CTL + 1)
         vmem = 4 * state_bytes + 4 * tab.size + (16 << 20)
         return pl.pallas_call(
-            kern, grid=(Bp // tile,), in_specs=in_specs,
+            kern, name="cgra_sweep", grid=(Bp // tile,), in_specs=in_specs,
             out_specs=state_specs,
             out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                        for x in state],

@@ -42,6 +42,7 @@ from typing import Dict, Iterator, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..analysis import pareto as _pareto
 from .runner import RESULT_FIELDS, _RESULT_DTYPES
 from .transport import WIRE_VERSION, sweep_to_wire
@@ -245,9 +246,10 @@ class SweepClient:
                         continue
                     if "status" in msg:
                         if msg["status"] == "complete":
-                            return self._finish(
-                                msg, arrays, acc, reduced,
-                                len(list(programs)), reduce, stats)
+                            with obs.span("client.fold", cid=cid):
+                                return self._finish(
+                                    msg, arrays, acc, reduced,
+                                    len(list(programs)), reduce, stats)
                         if msg["status"] == "drained":
                             raise _CampaignGone(cid)
                         raise TransportError(
@@ -255,15 +257,16 @@ class SweepClient:
                     cur = int(msg["cursor"])
                     if cur < acked:
                         stats.duplicate_records += 1
-                    if reduced:
-                        part = _pareto.reduced_from_wire(msg["arrays"])
-                        acc = part if acc is None else \
-                            _pareto.merge_reduced(reduce, [acc, part])
-                    else:
-                        lo, hi = int(msg["lo"]), int(msg["hi"])
-                        for f in RESULT_FIELDS:
-                            arrays[f][lo:hi] = \
-                                _pareto.array_from_wire(msg["arrays"][f])
+                    with obs.span("client.fold", cid=cid):
+                        if reduced:
+                            part = _pareto.reduced_from_wire(msg["arrays"])
+                            acc = part if acc is None else \
+                                _pareto.merge_reduced(reduce, [acc, part])
+                        else:
+                            lo, hi = int(msg["lo"]), int(msg["hi"])
+                            for f in RESULT_FIELDS:
+                                arrays[f][lo:hi] = \
+                                    _pareto.array_from_wire(msg["arrays"][f])
                     stats.records_folded += 1
                     acked = max(acked, cur + 1)
                     failures = 0       # progress resets the budget

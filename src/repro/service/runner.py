@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..analysis import pareto as _pareto
 from ..checkpoint import CheckpointManager
 from ..checkpoint.manager import load_tree
@@ -446,7 +447,8 @@ class ResumableSweepRunner:
                     fn = self._fn_for(stage)
                     res = fn(idx, hw, gi) if lane is None \
                         else fn(idx, hw, gi, lane)
-                    res = jax.block_until_ready(res)
+                    with obs.span("runner.wait"):
+                        res = jax.block_until_ready(res)
                     secs = self.clock() - t0
                     if self.injector is not None:
                         secs += self.injector.extra_seconds(k)
@@ -473,55 +475,59 @@ class ResumableSweepRunner:
 
     def run_unit(self, k: int) -> Tuple[UnitRecord, Dict[str, np.ndarray]]:
         """Execute (and commit) one pending unit."""
-        lo, hi = self._unit_range(k)
-        # every live worker participates in the unit (SPMD) and beats;
-        # injected-dead nodes go silent from their configured unit on
-        for n in self.monitor.nodes:
-            if self.injector is None or not self.injector.node_dead(n, k):
-                self.monitor.beat(n)
-        failed = set(self.monitor.confirmed_failed()) | self._pending_replace
-        if failed:
-            self._replan(k, failed)
-        node = self.monitor.nodes[k % len(self.monitor.nodes)]
+        with obs.span("runner.unit", unit=k):
+            lo, hi = self._unit_range(k)
+            # every live worker participates in the unit (SPMD) and beats;
+            # injected-dead nodes go silent from their configured unit on
+            for n in self.monitor.nodes:
+                if self.injector is None or not self.injector.node_dead(n, k):
+                    self.monitor.beat(n)
+            failed = (set(self.monitor.confirmed_failed())
+                      | self._pending_replace)
+            if failed:
+                self._replan(k, failed)
+            node = self.monitor.nodes[k % len(self.monitor.nodes)]
 
-        stage, attempts, secs, res = self._execute(k)
-        if self.reduce is not None:
-            # compacted (G, K) candidate set -- kilobytes, not the lane
-            # slice; pad lanes were masked on device, nothing to trim
-            res_np = {f: np.asarray(getattr(res, f))
-                      for f in _pareto.REDUCED_FIELDS}
-        else:
-            res_np = {f: np.asarray(getattr(res, f))[:hi - lo]
-                      for f in RESULT_FIELDS}
-        if stage.name != self._chain[0].name:
-            self.report.degraded[k] = stage.name
-        rec = UnitRecord(unit=k, lo=lo, hi=hi, backend=stage.name,
-                         attempts=attempts, resumed=False, seconds=secs,
-                         node=node)
-        self.report.units_run += 1
-        self.report.records.append(rec)
+            stage, attempts, secs, res = self._execute(k)
+            if self.reduce is not None:
+                # compacted (G, K) candidate set -- kilobytes, not the lane
+                # slice; pad lanes were masked on device, nothing to trim
+                res_np = {f: np.asarray(getattr(res, f))
+                          for f in _pareto.REDUCED_FIELDS}
+            else:
+                res_np = {f: np.asarray(getattr(res, f))[:hi - lo]
+                          for f in RESULT_FIELDS}
+            if stage.name != self._chain[0].name:
+                self.report.degraded[k] = stage.name
+            rec = UnitRecord(unit=k, lo=lo, hi=hi, backend=stage.name,
+                             attempts=attempts, resumed=False, seconds=secs,
+                             node=node)
+            self.report.units_run += 1
+            self.report.records.append(rec)
 
-        actions = self.monitor.observe_unit(node, secs)
-        for n, act in actions.items():
-            self.report.straggler_actions.append(
-                {"unit": k, "node": n, "action": act})
-            if (self.report.suggested_unit_size is None
-                    and self.unit_size > 1):
-                self.report.suggested_unit_size = max(self.unit_size // 2, 1)
-            if act == "replace":
-                self._pending_replace.add(n)
+            actions = self.monitor.observe_unit(node, secs)
+            for n, act in actions.items():
+                self.report.straggler_actions.append(
+                    {"unit": k, "node": n, "action": act})
+                if (self.report.suggested_unit_size is None
+                        and self.unit_size > 1):
+                    self.report.suggested_unit_size = max(
+                        self.unit_size // 2, 1)
+                if act == "replace":
+                    self._pending_replace.add(n)
 
-        self._results[k] = res_np
-        if self.mgr is not None:
-            if self.injector is not None:
-                self.injector.on_commit(k)     # kill point: pre-durability
-            self.mgr.save(res_np, k, extra={
-                "fingerprint": self.fingerprint, "lo": lo, "hi": hi,
-                "backend": stage.name, "attempts": attempts,
-            }, block=not self.ckpt_async)
-        if self.on_unit is not None:
-            self.on_unit(rec, res_np)
-        return rec, res_np
+            self._results[k] = res_np
+            if self.mgr is not None:
+                if self.injector is not None:
+                    self.injector.on_commit(k)     # kill point: pre-durability
+                with obs.span("runner.checkpoint", unit=k):
+                    self.mgr.save(res_np, k, extra={
+                        "fingerprint": self.fingerprint, "lo": lo, "hi": hi,
+                        "backend": stage.name, "attempts": attempts,
+                    }, block=not self.ckpt_async)
+            if self.on_unit is not None:
+                self.on_unit(rec, res_np)
+            return rec, res_np
 
     def mark_skipped(self, k: int):
         """Give up on a unit (deadline-expired request): its lanes stitch

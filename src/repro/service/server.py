@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..analysis import pareto as _pareto
 from ..core.autotune import AUTO, DEFAULT_MAX_BUCKETS, is_auto
 from ..core.characterization import Profile
@@ -275,8 +276,9 @@ class SweepService:
         self.completed: Dict[int, RequestResult] = {}
         self._next_rid = 0
         # admission audit trail: one record per packed slot, for tests
-        # and ops visibility ({rids, t_max, window_tmaxes, bucket_by})
-        self.admission_log: List[dict] = []
+        # and ops visibility ({rids, t_max, window_tmaxes, bucket_by});
+        # the newest 1,024, so a long-lived service stays bounded
+        self.admission_log: "deque[dict]" = deque(maxlen=1024)
         # per-kernel observed ``steps_executed`` maxima (keyed by program
         # name), updated as campaigns finish.  Static length is only a
         # proxy for convoy cost -- a data-dependent tight loop makes a
@@ -358,31 +360,36 @@ class SweepService:
                 pack = [r for i, r in enumerate(pack) if i in keep]
                 for r in reversed(rest):
                     self.queue.appendleft(r)
-            plan, members = _merge_plans(pack)
-            self.admission_log.append({
-                "rids": [r.rid for r in pack],
-                "t_max": int(plan.batch.t_max),
-                "window_tmaxes": [int(t) for t in tmaxes],
-                "bucket_by": "observed_steps" if by_steps else "length"})
-            runner = ResumableSweepRunner(
-                plan=plan, profile=self.profile, unit_size=self.unit_size,
-                max_steps=self.max_steps, mem_size=self.mem_size,
-                backend=self.backend, retry=self.retry,
-                reduce=pack[0].reduce, **self.runner_kw)
-            slot = _Slot(runner, members)
-            self._slots[si] = slot
-            if self.ckpt_root:
-                # fingerprint-keyed directory: an identical re-submission
-                # (post-restart) resumes its completed units; a different
-                # campaign lands in a different directory by construction
-                runner.attach_checkpoints(os.path.join(
-                    self.ckpt_root, runner.fingerprint[:24]))
-                # resumed units never pass through run_unit, so their
-                # partials must be replayed here or a streaming client
-                # would fold an incomplete set
-                for k in sorted(runner._results):
-                    self._deliver_partial(slot, *runner._unit_range(k),
-                                          runner._results[k])
+            now = self.clock()
+            obs.COUNTS["service.admitted"] += len(pack)
+            obs.COUNTS["service.queue_wait_s"] += sum(
+                now - r.submitted_at for r in pack)
+            with obs.span("service.admit", rids=[r.rid for r in pack]):
+                plan, members = _merge_plans(pack)
+                self.admission_log.append({
+                    "rids": [r.rid for r in pack],
+                    "t_max": int(plan.batch.t_max),
+                    "window_tmaxes": [int(t) for t in tmaxes],
+                    "bucket_by": "observed_steps" if by_steps else "length"})
+                runner = ResumableSweepRunner(
+                    plan=plan, profile=self.profile, unit_size=self.unit_size,
+                    max_steps=self.max_steps, mem_size=self.mem_size,
+                    backend=self.backend, retry=self.retry,
+                    reduce=pack[0].reduce, **self.runner_kw)
+                slot = _Slot(runner, members)
+                self._slots[si] = slot
+                if self.ckpt_root:
+                    # fingerprint-keyed directory: an identical re-submission
+                    # (post-restart) resumes its completed units; a different
+                    # campaign lands in a different directory by construction
+                    runner.attach_checkpoints(os.path.join(
+                        self.ckpt_root, runner.fingerprint[:24]))
+                    # resumed units never pass through run_unit, so their
+                    # partials must be replayed here or a streaming client
+                    # would fold an incomplete set
+                    for k in sorted(runner._results):
+                        self._deliver_partial(slot, *runner._unit_range(k),
+                                              runner._results[k])
 
     # -- execution ----------------------------------------------------------
     def _expire(self, slot: _Slot):
@@ -453,36 +460,37 @@ class SweepService:
 
     def _finish(self, si: int):
         slot = self._slots[si]
-        red = slot.runner.reduce
-        full = slot.runner.stitch(require_complete=False)
-        if red is not None:
-            arrays = {f: np.asarray(getattr(full, f))
-                      for f in _pareto.REDUCED_FIELDS}
-        else:
-            arrays = {f: np.asarray(getattr(full, f))
-                      for f in RESULT_FIELDS}
-        skipped = set(slot.runner._skipped)
-        for (r, lo, hi), (plo, phi) in zip(slot.members, slot.prog_spans):
-            sk = sum(max(0, min(hi, uhi) - max(lo, ulo))
-                     for k in skipped
-                     for ulo, uhi in [slot.runner._unit_range(k)])
-            degr = {k: v for k, v in slot.runner.report.degraded.items()
-                    if max(lo, slot.runner._unit_range(k)[0])
-                    < min(hi, slot.runner._unit_range(k)[1])}
+        with obs.span("service.finish", rids=[r.rid for r in slot.requests()]):
+            red = slot.runner.reduce
+            full = slot.runner.stitch(require_complete=False)
             if red is not None:
-                req_arrays = _request_rows(arrays, plo, phi, lo)
+                arrays = {f: np.asarray(getattr(full, f))
+                          for f in _pareto.REDUCED_FIELDS}
             else:
-                req_arrays = {f: arrays[f][lo:hi] for f in RESULT_FIELDS}
-            # trip-count history records per-CANDIDATE rows (aligned
-            # with r.programs), so it must run before any mapping fold
-            self._record_steps(r, req_arrays, reduced=red is not None)
-            if red is not None and r.mappings is not None:
-                req_arrays = _fold_request(red, req_arrays, r.mappings)
-            self.completed[r.rid] = RequestResult(
-                rid=r.rid, arrays=req_arrays,
-                expired=r.rid in slot.expired,
-                degraded_units=degr, skipped_lanes=sk)
-        self._slots[si] = None
+                arrays = {f: np.asarray(getattr(full, f))
+                          for f in RESULT_FIELDS}
+            skipped = set(slot.runner._skipped)
+            for (r, lo, hi), (plo, phi) in zip(slot.members, slot.prog_spans):
+                sk = sum(max(0, min(hi, uhi) - max(lo, ulo))
+                         for k in skipped
+                         for ulo, uhi in [slot.runner._unit_range(k)])
+                degr = {k: v for k, v in slot.runner.report.degraded.items()
+                        if max(lo, slot.runner._unit_range(k)[0])
+                        < min(hi, slot.runner._unit_range(k)[1])}
+                if red is not None:
+                    req_arrays = _request_rows(arrays, plo, phi, lo)
+                else:
+                    req_arrays = {f: arrays[f][lo:hi] for f in RESULT_FIELDS}
+                # trip-count history records per-CANDIDATE rows (aligned
+                # with r.programs), so it must run before any mapping fold
+                self._record_steps(r, req_arrays, reduced=red is not None)
+                if red is not None and r.mappings is not None:
+                    req_arrays = _fold_request(red, req_arrays, r.mappings)
+                self.completed[r.rid] = RequestResult(
+                    rid=r.rid, arrays=req_arrays,
+                    expired=r.rid in slot.expired,
+                    degraded_units=degr, skipped_lanes=sk)
+            self._slots[si] = None
 
     def step(self) -> bool:
         """Admit + advance every active slot by one work unit; returns

@@ -67,6 +67,7 @@ from urllib.parse import parse_qs, urlparse
 
 import numpy as np
 
+from .. import obs
 from ..analysis import pareto as _pareto
 from ..core.hwconfig import HwConfig
 from ..core.program import Program
@@ -244,11 +245,12 @@ class SweepTransport:
     def _run(self):
         try:
             while not self._drain_req.is_set():
-                with self._lock:
+                with self._lock, obs.span("transport.step"):
                     busy = self.service.step()
                     self._sync_completed()
                 if not busy:
-                    self._work.wait(self.poll_s)
+                    with obs.span("transport.idle"):
+                        self._work.wait(self.poll_s)
                     self._work.clear()
             self._do_drain()
         finally:
@@ -388,9 +390,10 @@ class _Handler(BaseHTTPRequestHandler):
             self._json(503, {"error": "draining"}, {"Retry-After": "1"})
             return
         try:
-            n = int(self.headers.get("Content-Length", 0))
-            body = json.loads(self.rfile.read(n))
-            cid, created, status = t.submit(body)
+            with obs.span("transport.submit"):
+                n = int(self.headers.get("Content-Length", 0))
+                body = json.loads(self.rfile.read(n))
+                cid, created, status = t.submit(body)
         except ServiceOverloaded as e:
             self._json(429, {"error": str(e)}, {"Retry-After": "1"})
             return
@@ -465,24 +468,27 @@ class _Handler(BaseHTTPRequestHandler):
             if not recs and status in ("queued", "running"):
                 self._line({"heartbeat": True, "cursor": sent})
                 continue
-            for raw in recs:
-                if inj is not None:
-                    delay = inj.delay_record(camp.cid, sent)
-                    if delay > 0:
-                        time.sleep(delay)
-                self._line(raw)
-                if inj is not None and inj.duplicate_record(camp.cid, sent):
-                    self._line(raw)          # at-least-once, made visible
-                sent += 1
-                sent_here += 1
-                if budget is not None and sent_here >= budget:
-                    # chaos: cut the connection without a terminal line;
-                    # the client reconnects at cursor=sent
-                    self.close_connection = True
+            with obs.span("transport.send", cid=camp.cid):
+                for raw in recs:
+                    if inj is not None:
+                        delay = inj.delay_record(camp.cid, sent)
+                        if delay > 0:
+                            time.sleep(delay)
+                    self._line(raw)
+                    if inj is not None and inj.duplicate_record(camp.cid,
+                                                                sent):
+                        self._line(raw)      # at-least-once, made visible
+                    sent += 1
+                    sent_here += 1
+                    if budget is not None and sent_here >= budget:
+                        # chaos: cut the connection without a terminal
+                        # line; the client reconnects at cursor=sent
+                        self.close_connection = True
+                        return
+                if status not in ("queued", "running"):
+                    self._line({"status": status, "cursor": sent,
+                                **terminal})
                     return
-            if status not in ("queued", "running"):
-                self._line({"status": status, "cursor": sent, **terminal})
-                return
 
 
 # ---------------------------------------------------------------------------

@@ -507,7 +507,7 @@ def test_service_buckets_by_observed_steps_history(profile):
     for r in (r1, r2, r3, r4):
         svc.submit(r)
     svc.drain()
-    by_steps = [rec for rec in svc.admission_log[1:]
+    by_steps = [rec for rec in list(svc.admission_log)[1:]
                 if rec["bucket_by"] == "observed_steps"]
     assert by_steps, svc.admission_log
     # the first observed-steps slot packs the two fast requests together
