@@ -42,7 +42,7 @@ from .autotune import (AUTO, ShapeClass, autotune_enabled, default_blk_b,
                        default_cache, is_auto, tune_sweep)
 from .cgra import init_state, make_exec_fn, rows_from_fused
 from .characterization import Profile
-from .hwconfig import HwConfig, stack_configs
+from .hwconfig import HwConfig, _stack_fields
 from .memory import (DEFAULT_MAX_BANKS, scoreboard_bound,
                      validate_bank_bound)
 from .program import (MappingSet, Program, ProgramBatch, as_program_batch,
@@ -424,13 +424,14 @@ def plan_grid(program: Union[Program, ProgramBatch, Sequence[Program], None]
         batch = as_program_batch(program)
         G = batch.n_programs
         H, D = len(hw_configs), mem_images.shape[0]
-        n_banks_req = max(int(np.asarray(c.n_banks)) for c in hw_configs)
-        max_banks = scoreboard_bound(max(n_banks_req, DEFAULT_MAX_BANKS))
-        hw_b = stack_configs(list(hw_configs))
-        # broadcast to the full flat grid: hw h repeats over the data axis,
-        # then the (hw x data) block tiles over the program axis
-        hw_grid = jax.tree.map(
-            lambda x: jnp.tile(jnp.repeat(x, D, axis=0), G), hw_b)
+        fields = _stack_fields(list(hw_configs))
+        max_banks = scoreboard_bound(max(int(fields["n_banks"].max()),
+                                         DEFAULT_MAX_BANKS))
+        # broadcast to the full flat grid on the host: hw h repeats over
+        # the data axis, then the (hw x data) block tiles over the program
+        # axis; each field then reaches the device in one transfer
+        hw_grid = jax.device_put(HwConfig(**{
+            f: np.tile(np.repeat(x, D), G) for f, x in fields.items()}))
         images = jnp.asarray(mem_images, jnp.int32)          # (D, M), one copy
         img_idx = np.tile(np.arange(D, dtype=np.int32), G * H)      # (G*H*D,)
         prog_idx = np.repeat(np.arange(G, dtype=np.int32), H * D)

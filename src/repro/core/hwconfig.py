@@ -121,16 +121,31 @@ TOPOLOGIES = {
 }
 
 
-def stack_configs(configs) -> HwConfig:
-    """Stack a list of HwConfig into one batched HwConfig (leading axis) for
-    vmap-based design-space sweeps."""
+_DTYPES = {f: np.float32 if f in ("smul_power_scale", "t_clk_ns")
+           else np.int32 for f in HwConfig.FIELDS}
+
+
+def _stack_fields(configs) -> Dict[str, Any]:
+    """Each field of ``configs`` as one ``(n,)`` array, float32 for
+    ``smul_power_scale`` and ``t_clk_ns`` and int32 for the rest.
+
+    The arrays are numpy, built on the host, so a caller expands the grid
+    there and then moves each field to the device in one transfer
+    (``stack_configs``, ``dse.plan_grid``, the service's
+    ``_merge_plans``).  Where a configuration holds a tracer the fields
+    are stacked with ``jnp`` inside the trace instead."""
     obs.COUNTS["hwconfig.configs_stacked"] += len(configs)
     with obs.span("hwconfig.stack", n=len(configs)):
-        leaves = [jnp.stack([jnp.asarray(getattr(c, f), jnp.float32)
-                             if f in ("smul_power_scale", "t_clk_ns")
-                             else jnp.asarray(getattr(c, f), jnp.int32)
-                             for c in configs]) for f in HwConfig.FIELDS]
-    cfg = HwConfig.__new__(HwConfig)
-    for f, v in zip(HwConfig.FIELDS, leaves):
-        setattr(cfg, f, v)
-    return cfg
+        cols = {f: [getattr(c, f) for c in configs] for f in HwConfig.FIELDS}
+        if any(isinstance(v, jax.core.Tracer)
+               for col in cols.values() for v in col):
+            return {f: jnp.stack([jnp.asarray(v, _DTYPES[f]) for v in col])
+                    for f, col in cols.items()}
+        return {f: np.asarray(col, _DTYPES[f]) for f, col in cols.items()}
+
+
+def stack_configs(configs) -> HwConfig:
+    """Stack a list of HwConfig into one batched HwConfig (leading axis) for
+    vmap-based design-space sweeps: device arrays ``(n,)``, one transfer
+    per field."""
+    return jax.device_put(HwConfig(**_stack_fields(configs)))

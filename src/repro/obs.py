@@ -22,8 +22,9 @@ import jax
 PREFIX = "repro."
 
 COUNTS: Dict[str, float] = {
-    # hardware configurations stacked into device arrays
-    # (``hwconfig.stack_configs``)
+    # hardware configurations stacked into batched fields
+    # (``hwconfig._stack_fields``: ``stack_configs``, ``dse.plan_grid``
+    # and the service's admission)
     "hwconfig.configs_stacked": 0,
     # requests the sweep service admitted into a slot, and the seconds
     # they waited in its queue before that (``SweepService._admit``)

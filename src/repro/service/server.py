@@ -75,7 +75,7 @@ from ..analysis import pareto as _pareto
 from ..core.autotune import AUTO, DEFAULT_MAX_BUCKETS, is_auto
 from ..core.characterization import Profile
 from ..core.dse import GridPlan
-from ..core.hwconfig import stack_configs
+from ..core.hwconfig import HwConfig, _stack_fields
 from ..core.program import MappingSet, bucket_boundaries, pack_programs
 from .runner import RESULT_FIELDS, ResumableSweepRunner, RetryPolicy
 
@@ -193,19 +193,21 @@ def _merge_plans(requests: Sequence[SweepRequest]) -> Tuple[
                        + img_off)
         prog_idx.append(np.repeat(np.arange(G, dtype=np.int32), H * D)
                         + prog_off)
-        hw_b = stack_configs(list(r.hw_configs))
-        hw_parts.append(jax.tree.map(
-            lambda x: jnp.tile(jnp.repeat(x, D, axis=0), G), hw_b))
+        hw_parts.append({f: np.tile(np.repeat(x, D), G) for f, x in
+                         _stack_fields(list(r.hw_configs)).items()})
         n = G * H * D
         members.append((r, lane_off, lane_off + n))
         prog_off, img_off, lane_off = prog_off + G, img_off + D, \
             lane_off + n
-    hw_grid = jax.tree.map(lambda *xs: jnp.concatenate(xs), *hw_parts)
+    # the requests' grids join on the host; each field then reaches the
+    # device in one transfer
+    hw_flat = {f: np.concatenate([p[f] for p in hw_parts])
+               for f in HwConfig.FIELDS}
+    hw_grid = jax.device_put(HwConfig(**hw_flat))
 
     from ..core.memory import DEFAULT_MAX_BANKS, scoreboard_bound
-    n_banks_req = max(int(np.asarray(c.n_banks))
-                      for r in requests for c in r.hw_configs)
-    max_banks = scoreboard_bound(max(n_banks_req, DEFAULT_MAX_BANKS))
+    max_banks = scoreboard_bound(max(int(hw_flat["n_banks"].max()),
+                                     DEFAULT_MAX_BANKS))
     plan = GridPlan(batch, jnp.asarray(images, jnp.int32),
                     np.concatenate(img_idx), np.concatenate(prog_idx),
                     hw_grid, max_banks)
