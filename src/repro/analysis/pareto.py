@@ -11,11 +11,15 @@ at the winners.  This module defines *reduction specs* — :class:`TopK`
   padded / foreign lanes are masked with ``+inf`` sentinels and a
   ``lane_idx < 0`` validity convention) that runs inside the compiled
   sweep so the ``(B,)`` grid never leaves the device,
-* a **numpy oracle** (independent O(n^2) reference) the device path is
-  bit-identical to, and
-* an **associative host-side merge** (:func:`merge_reduced`) so per-bucket,
-  per-device, and per-work-unit candidate sets — each only ``O(G*K)``
-  numbers — combine to exactly the monolithic answer.
+* a **numpy oracle** (:func:`reduce_oracle`, an independent O(n^2)
+  reference) that the device path and the host merge are bit-identical
+  to; tests compare against it and no production path calls it, and
+* an **associative host-side merge** (:func:`merge_reduced`,
+  :func:`fold_segments`) so per-bucket, per-device, and per-work-unit
+  candidate sets — each only ``O(G*K)`` numbers — combine to exactly the
+  monolithic answer.  It sorts the pooled candidates once and scans them
+  in numpy, the device reducer's algorithm, with no Python loop over
+  slots, candidates or segments.
 
 Every candidate is tagged with its *original flat grid index* so clients
 can recover ``(g, h, d)`` coordinates: ``g = idx // (H*D)``,
@@ -42,6 +46,8 @@ import functools
 from typing import NamedTuple, Sequence, Tuple, Union
 
 import numpy as np
+
+from .. import obs
 
 # Mirrors ``repro.core.dse.SweepResult._fields`` (kept literal to avoid an
 # import cycle: core.dse imports this module for the ``reduce=`` API).
@@ -244,11 +250,12 @@ def merge_reduced(spec: Reduction,
     """Merge candidate sets from buckets / devices / work units.
 
     Associative and idempotent: candidates are pooled per segment,
-    deduplicated by flat grid index, and re-reduced with the numpy oracle
-    (each part is only ``(G, K)``, so this is cheap).  Exact for
-    :class:`TopK` always, and for :class:`ParetoFront` whenever no input
-    part was clipped; residual ``clipped`` counts are carried through so
-    callers can detect inexactness.
+    deduplicated by flat grid index (the first in part order stays) and
+    re-reduced by :func:`_reduce_pooled`, bit-identical to
+    :func:`reduce_oracle` over the pool (each part is only ``(G, K)``).
+    Exact for :class:`TopK` always, and for :class:`ParetoFront` whenever
+    no input part was clipped; residual ``clipped`` counts are carried
+    through so callers can detect inexactness.
     """
     parts = [p for p in parts if p is not None]
     if not parts:
@@ -260,24 +267,98 @@ def merge_reduced(spec: Reduction,
         [np.asarray(getattr(p, f)) for p in parts], axis=1)
         for f in CANDIDATE_FIELDS}
     n = cat["indices"].shape[1]
-    lane = cat["indices"].astype(np.int64)
-    # Dedupe repeated lanes (e.g. a re-delivered partial): keep first.
-    for g in range(G):
-        seen = set()
-        for j in range(n):
-            ix = lane[g, j]
-            if ix < 0:
-                continue
-            if ix in seen:
-                lane[g, j] = -1
-            else:
-                seen.add(ix)
-    prog = np.repeat(np.arange(G), n)
-    fields = tuple(cat[f].reshape(-1) for f in RESULT_FIELDS)
-    red = reduce_oracle(spec, fields, prog, lane.reshape(-1), G)
-    carried = np.sum([np.asarray(p.clipped) for p in parts], axis=0)
-    return red._replace(
-        clipped=(red.clipped + carried).astype(np.int32))
+    lane = cat["indices"].astype(np.int64).reshape(-1)
+    pos = np.flatnonzero(lane >= 0)
+    obs.COUNTS["pareto.candidates_merged"] += int(pos.size)
+    with obs.span("pareto.merge", parts=len(parts), n=int(pos.size)):
+        # Dedupe repeated lanes (e.g. a re-delivered partial): the stable
+        # sort keeps pool order within a (segment, index) run, so the
+        # first of each run is the first occurrence.
+        seg, ix = pos // n, lane[pos]
+        order = np.lexsort((ix, seg))
+        s, ix = seg[order], ix[order]
+        keep = np.ones(pos.size, bool)
+        keep[order[1:][(s[1:] == s[:-1]) & (ix[1:] == ix[:-1])]] = False
+        pos = pos[keep]
+        red = _reduce_pooled(
+            spec, pos // n, lane[pos],
+            tuple(cat[f].reshape(-1)[pos] for f in RESULT_FIELDS), G)
+        carried = np.sum([np.asarray(p.clipped) for p in parts], axis=0)
+        return red._replace(
+            clipped=(red.clipped + carried).astype(np.int32))
+
+
+def _reduce_pooled(spec: Reduction, seg, lane, fields,
+                   n_segments: int) -> ReducedResult:
+    """The host merge's reduction: sort once, then scan.
+
+    ``seg`` and ``lane`` are each valid candidate's segment and flat grid
+    index, ``fields`` its five :data:`RESULT_FIELDS` values, all 1-D in
+    pool order.  The device reducer's algorithm in numpy: one lexsort by
+    ``(segment, objective(s), index)``, a Pareto dominance scan, a rank
+    within the segment and a scatter of the first ``K``.  Bit-identical
+    to :func:`reduce_oracle` over the same candidates, which is what the
+    tests hold it to.
+    """
+    G, K = int(n_segments), spec.k_out
+    if isinstance(spec, TopK):
+        key = objective_values(spec.objective, fields)
+        order = np.lexsort((lane, key, seg))
+        eligible = np.ones(order.size, bool)
+    else:
+        a = objective_values(spec.axes[0], fields)
+        b = objective_values(spec.axes[1], fields)
+        order = np.lexsort((lane, b, a, seg))
+        eligible = _front(seg[order], a[order], b[order])
+    sseg = seg[order]
+    first = np.ones(order.size, bool)
+    first[1:] = sseg[1:] != sseg[:-1]
+    before = np.cumsum(eligible) - eligible       # eligible ones earlier
+    start = np.maximum.accumulate(
+        np.where(first, np.arange(order.size), 0))
+    rank = before - before[start]
+    take = eligible & (rank < K)
+    rows, cols, src = sseg[take], rank[take], order[take]
+    out = {f: np.zeros((G, K), _OUT_DTYPES[f]) for f in CANDIDATE_FIELDS}
+    out["indices"][:] = -1
+    out["indices"][rows, cols] = lane[src]
+    for i, f in enumerate(RESULT_FIELDS):
+        out[f][rows, cols] = fields[i][src].astype(_OUT_DTYPES[f])
+    tot = np.bincount(sseg[eligible], minlength=G)
+    clipped = (np.zeros((G,), np.int32) if isinstance(spec, TopK)
+               else np.maximum(tot - K, 0).astype(np.int32))
+    return ReducedResult(count=np.minimum(tot, K).astype(np.int32),
+                         clipped=clipped, **out)
+
+
+def _front(seg, a, b):
+    """Non-dominated mask of candidates sorted by ``(seg, a, b, index)``.
+
+    A point is dominated when an earlier point of its segment with a
+    strictly smaller ``a`` has ``b <=`` its own, or when the first point
+    of its own ``a``-run has a strictly smaller ``b``.  A NaN on either
+    axis compares false, so such a point neither dominates nor is
+    dominated, as in :func:`reduce_oracle`; the scan skips it.
+    """
+    front = np.ones(seg.size, bool)
+    num = ~(np.isnan(a) | np.isnan(b))
+    seg, a, b = seg[num], a[num], b[num]
+    i = np.arange(seg.size)
+    new_seg = np.ones(seg.size, bool)
+    new_seg[1:] = seg[1:] != seg[:-1]
+    new_run = new_seg.copy()
+    new_run[1:] |= a[1:] != a[:-1]
+    run0 = np.maximum.accumulate(np.where(new_run, i, 0))
+    seg0 = np.maximum.accumulate(np.where(new_seg, i, 0))
+    # b's rank (equal values, equal rank), lowered by segment so that a
+    # running minimum restarts at each segment: integers, exact for every
+    # float, infinities included
+    rb = np.searchsorted(np.sort(b), b) - seg * (seg.size + 1)
+    low = np.minimum.accumulate(rb)
+    dominated = (((run0 > seg0) & (low[np.maximum(run0 - 1, 0)] <= rb))
+                 | (b[run0] < b))
+    front[num] = ~dominated
+    return front
 
 
 def remap_segments(part: ReducedResult, prog_map, index_offsets,
@@ -317,7 +398,8 @@ def fold_segments(spec: Reduction, part: ReducedResult, seg_of,
     sweep ships back each kernel's best-mapping front.  Unlike
     :func:`remap_segments` (a pure *relabeling*, rows must be distinct),
     folding POOLS every source row that maps to the same target and
-    re-reduces with the numpy oracle, exactly like :func:`merge_reduced`.
+    re-reduces it like :func:`merge_reduced` (bit-identical to
+    :func:`reduce_oracle` over the pooled rows; nothing is deduplicated).
     Candidate ``indices`` are NOT shifted: a candidate's flat grid index
     already encodes its fine-segment coordinate (``idx // (H*D)`` is the
     flat candidate row), so the winning mapping id stays recoverable
@@ -335,14 +417,18 @@ def fold_segments(spec: Reduction, part: ReducedResult, seg_of,
     if seg.size and not (0 <= seg.min() and seg.max() < n_out):
         raise ValueError(
             f"fold_segments: seg_of out of range [0, {n_out})")
-    prog = np.repeat(seg, K)
-    fields = tuple(getattr(part, f).reshape(-1) for f in RESULT_FIELDS)
-    red = reduce_oracle(spec, fields, prog, part.indices.reshape(-1),
-                        n_out)
-    carried = np.zeros((n_out,), np.int64)
-    np.add.at(carried, seg, part.clipped.astype(np.int64))
-    return red._replace(
-        clipped=(red.clipped + carried).astype(np.int32))
+    lane = part.indices.astype(np.int64).reshape(-1)
+    pos = np.flatnonzero(lane >= 0)
+    obs.COUNTS["pareto.candidates_merged"] += int(pos.size)
+    with obs.span("pareto.merge", parts=1, n=int(pos.size)):
+        red = _reduce_pooled(
+            spec, seg[pos // K], lane[pos],
+            tuple(getattr(part, f).reshape(-1)[pos] for f in RESULT_FIELDS),
+            n_out)
+        carried = np.zeros((n_out,), np.int64)
+        np.add.at(carried, seg, part.clipped.astype(np.int64))
+        return red._replace(
+            clipped=(red.clipped + carried).astype(np.int32))
 
 
 def _as_numpy(r: ReducedResult) -> ReducedResult:

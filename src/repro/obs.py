@@ -30,6 +30,10 @@ COUNTS: Dict[str, float] = {
     # they waited in its queue before that (``SweepService._admit``)
     "service.admitted": 0,
     "service.queue_wait_s": 0.0,
+    # valid candidates pooled by the host merge of reduced candidate
+    # sets (``pareto.merge_reduced`` of two or more parts,
+    # ``pareto.fold_segments``), duplicates included
+    "pareto.candidates_merged": 0,
 }
 
 
