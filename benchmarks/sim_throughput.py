@@ -181,7 +181,7 @@ def _bench_multi_kernel_one(rep: Report, ks: list) -> dict:
     lru-cached operand executable per bucket, per-bucket autotuned
     chunk/blk knobs, held across calls via ``dse.make_bucketed_sweep_fn``
     -- the service steady state) vs the per-program python loop at the
-    engine defaults (one constant-closure compile per kernel).  XLA
+    engine defaults (one outer ``jax.jit`` compile per kernel).  XLA
     backend -- the compile-amortization story is backend-independent and
     the interpret-mode Pallas numbers would only measure the interpreter.
 

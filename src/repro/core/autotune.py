@@ -11,9 +11,8 @@ stack one answer to "what config should this shape run with":
     defaults (32 / 64 / 4) on a miss -- so an untuned system behaves
     exactly as before;
   * ``tune_sweep`` times a small candidate grid on the *actual* sweep
-    (first encounter of a shape class, or an explicit pre-warm pass) and
-    persists the winner, so the heterogeneous request mix a real service
-    sees is tuned automatically;
+    (an explicit pre-warm pass) and persists the winner, so every later
+    sweep of that shape class runs with it;
   * the cache file is schema-validated (``autotune_schema.json``, the
     same discipline as ``benchmarks/bench_schema.json``): a corrupt file,
     a stale version, or a malformed entry is *dropped*, never fatal --
@@ -21,9 +20,9 @@ stack one answer to "what config should this shape run with":
 
 Consulted by ``dse.sweep`` (every knob defaults to ``AUTO``), by
 ``service.runner.ResumableSweepRunner`` (blk_b / chunk_steps) and by
-``service.server.SweepService`` (bucket count of request packing).
-Opt into *automatic* first-encounter tuning with ``REPRO_AUTOTUNE=1``
-(or ``dse.sweep(..., autotune=True)``); cache location override:
+``service.server.SweepService`` (bucket count of request packing).  No
+sweep times anything itself: ``tune_sweep`` fills the cache ahead of
+time.  Cache location override:
 ``REPRO_AUTOTUNE_CACHE=/path/to/cache.json``.
 """
 from __future__ import annotations
@@ -53,7 +52,6 @@ DEFAULT_CHUNK_STEPS = 64
 DEFAULT_MAX_BUCKETS = 4
 CACHE_VERSION = 1
 _ENV_CACHE = "REPRO_AUTOTUNE_CACHE"
-_ENV_ENABLE = "REPRO_AUTOTUNE"
 
 
 def is_auto(*values) -> bool:
@@ -64,13 +62,6 @@ def is_auto(*values) -> bool:
 def default_blk_b(backend: str) -> int:
     """The untuned lane tile of a backend."""
     return PALLAS_BLK_B if backend == "pallas" else DEFAULT_BLK_B
-
-
-def autotune_enabled(flag: Optional[bool] = None) -> bool:
-    """Explicit flag wins; otherwise the REPRO_AUTOTUNE env opt-in."""
-    if flag is not None:
-        return bool(flag)
-    return os.environ.get(_ENV_ENABLE, "") not in ("", "0", "false", "no")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -318,7 +309,7 @@ def resolve_backend(shape: ShapeClass, *,
 
 
 def default_candidates(shape: ShapeClass, max_steps: int) -> List[dict]:
-    """The small first-encounter candidate grid: bucket counts that make
+    """The small candidate grid of a pre-warm pass: bucket counts that make
     sense for G, early-exit chunk sizes around the default, and (Pallas
     only) two batch tiles."""
     buckets = sorted({b for b in (1, 2, 4, min(shape.G, 8))
@@ -378,7 +369,7 @@ def tune_sweep(programs, profile, hw_configs, mem_images, *,
                     mem_images=mem_images, mesh=mesh, max_steps=max_steps,
                     mem_size=mem_size, backend=be, interpret=interpret,
                     chunk_steps=cand["chunk_steps"], blk_b=cand["blk_b"],
-                    max_buckets=cand["max_buckets"], autotune=False))
+                    max_buckets=cand["max_buckets"]))
             run()                             # compile + warm
             ts = []
             for _ in range(max(1, repeats)):

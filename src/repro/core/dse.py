@@ -15,16 +15,19 @@ mesh-sharded computation over all THREE design-space axes:
     estimate below, mirroring ``estimator.estimate(case="vi")``), so the
     full simulate->estimate path stays inside one jitted program -- no
     host round-trip per design point;
-  * sweep() shards the flattened (program x hw x data) grid over every
-    device of the mesh with one ``shard_map`` builder for both backends
-    (each device sweeps its own shard): on a 512-chip pod this is a
-    512-way data-parallel sweep, the deployable version of the paper's
-    tool.
+  * one campaign plan (``make_bucketed_sweep_fn``) buckets the kernels
+    by length, stacks the hardware configurations, packs each bucket and
+    builds its lane operands once; calling it dispatches every bucket,
+    waits once and merges once.  ``sweep()`` is one call of that plan;
+  * with a mesh, each bucket runs under one ``shard_map`` builder for
+    both backends (``_shard_call``, each device sweeps its own shard):
+    on a 512-chip pod this is a 512-way data-parallel sweep, the
+    deployable version of the paper's tool.
 
 Different *mappings* (programs) are packed to a common padded shape by
 ``program.pack_programs`` and swept as data: ONE compiled executable per
-backend covers the full G-kernel grid (``TRACE_COUNTS`` lets tests
-assert the no-retrace property).
+backend and length bucket covers the G-kernel grid (``TRACE_COUNTS``
+lets tests assert the no-retrace property).
 """
 from __future__ import annotations
 
@@ -38,16 +41,15 @@ import numpy as np
 from . import isa
 from .. import obs
 from ..analysis import pareto as _pareto
-from .autotune import (AUTO, ShapeClass, autotune_enabled, default_blk_b,
-                       default_cache, is_auto, tune_sweep)
+from .autotune import (AUTO, ShapeClass, default_blk_b, default_cache,
+                       is_auto, resolve_backend)
 from .cgra import init_state, make_exec_fn, rows_from_fused
 from .characterization import Profile
 from .hwconfig import HwConfig, _stack_fields
 from .memory import (DEFAULT_MAX_BANKS, scoreboard_bound,
                      validate_bank_bound)
 from .program import (MappingSet, Program, ProgramBatch, as_program_batch,
-                      batch_tables, bucket_programs, fused_rows,
-                      program_tables)
+                      batch_tables, bucket_programs, fused_rows)
 
 # Incremented once per trace of each backend's sweep body (a Python side
 # effect only runs while tracing, never while executing the compiled
@@ -92,10 +94,9 @@ def _sweep_body(exec_step, fused, base, n_instrs, tbl, mem_init,
                 mem_size: int) -> "SweepResult":
     """One lane's fused simulate+estimate scan over the fused row table.
 
-    ``fused`` is the ``program.fused_rows`` array -- ``(R, N_ROW_FIELDS,
-    P)`` where R is ``T`` (single-program constant) or ``G * T_max``
-    (stacked operand) -- and ``base`` is this lane's row offset
-    (``prog_idx * T_max``; 0 for the constant path).  Each step performs
+    ``fused`` is the ``program.fused_rows`` array -- ``(G * T_max,
+    N_ROW_FIELDS, P)`` -- and ``base`` is this lane's row offset
+    (``prog_idx * T_max``).  Each step performs
     ONE ``dynamic_slice`` row fetch at ``base + pc`` and shares the
     decoded instruction between the simulator (``cgra.make_exec_fn``)
     and the fused case-(vi) estimate; the previous instruction's
@@ -184,8 +185,7 @@ def _sweep_body(exec_step, fused, base, n_instrs, tbl, mem_init,
 @functools.lru_cache(maxsize=None)
 def _xla_sweep_core(rows: int, cols: int, mem_size: int, max_steps: int,
                     chunk: Optional[int], max_banks: int, t_max: int):
-    """One jitted sweep core per static configuration (the multi-program
-    path).
+    """One jitted sweep core per static configuration.
 
     The fused row table (``program.fused_rows``, flattened ``(G * T_max,
     N_ROW_FIELDS, P)``), per-program lengths, profile tables, memory
@@ -207,27 +207,6 @@ def _xla_sweep_core(rows: int, cols: int, mem_size: int, max_steps: int,
     return jax.jit(jax.vmap(one, in_axes=(None, None, None, 0, 0, 0)))
 
 
-def _xla_single_sweep_fn(program: Program, profile: Profile, rows: int,
-                         cols: int, mem_size: int, max_steps: int,
-                         chunk: Optional[int], max_banks: int):
-    """Seed-style single-program sweep: the fused row table is a closure
-    constant of an *unjitted* vmapped fn (the caller jits), keeping the
-    constant-folding-friendly data flow -- and the compile-per-program
-    cost -- of the original API.  Numerically identical to the operand
-    core with G=1."""
-    exec_step = make_exec_fn(rows, cols, mem_size, max_banks=max_banks)
-    fused = fused_rows(program_tables(program))      # (T, NF, P) constant
-    n_instrs = np.int32(program.n_instrs)
-    tbl = _profile_tables(profile)
-
-    def one(mem_init, hw: HwConfig):
-        TRACE_COUNTS["xla"] += 1          # trace-time only: retrace probe
-        return _sweep_body(exec_step, fused, np.int32(0), n_instrs, tbl,
-                           mem_init, hw, max_steps, chunk, mem_size)
-
-    return jax.vmap(one)
-
-
 def make_sweep_fn(program: Union[Program, ProgramBatch, Sequence[Program]],
                   profile: Profile, *, rows: int = 4,
                   cols: int = 4, mem_size: int = 4096, max_steps: int = 2048,
@@ -241,16 +220,14 @@ def make_sweep_fn(program: Union[Program, ProgramBatch, Sequence[Program]],
     fused into the simulation scan (single pass, no trace
     materialization -- O(1) memory per design point).
 
-    program: a single ``Program`` -> ``fn(mem_init (B, M), hw batched
-    (B,)) -> SweepResult`` (the original constant-closure API -- tables
-    are baked in as jit constants, fastest per-program data flow, one
-    compile per kernel); a sequence of programs or a ``ProgramBatch`` ->
+    program: a sequence of programs or a ``ProgramBatch`` ->
     ``fn(mem_init (B, M), hw (B,), prog_idx (B,))`` where each lane
     gathers its kernel from the packed ``(G, T_max, P)`` tables inside
     the jitted program and the tables are runtime operands of one cached
     executable per static configuration: sweeping a different kernel set
     of the same padded shape causes NO retrace (``TRACE_COUNTS``
-    observable).
+    observable).  A single ``Program`` -> ``fn(mem_init (B, M), hw
+    (B,))``: the same core with G = 1 and a zero ``prog_idx``.
 
     backend:
       * ``"xla"``    -- vmapped ``lax.scan`` over ``core.cgra.make_step_fn``
@@ -312,64 +289,58 @@ def make_sweep_fn(program: Union[Program, ProgramBatch, Sequence[Program]],
         raise ValueError(f"unknown sweep backend: {backend!r}")
 
     chunk = _norm_chunk(chunk_steps, max_steps)
+    batch = as_program_batch(program)
+    fused = jnp.asarray(fused_rows(batch_tables(batch)))      # (G*T, NF, P)
+    plen = jnp.asarray(batch.n_instrs, jnp.int32)             # (G,)
+    tbl = _profile_tables(profile)
+    core = _xla_sweep_core(rows, cols, mem_size, max_steps, chunk,
+                           max_banks, batch.t_max)
+
+    def fn(mem_init, hw: HwConfig, prog_idx) -> SweepResult:
+        if validate:
+            validate_bank_bound(hw.n_banks, max_banks,
+                                where="dse.make_sweep_fn(backend='xla')")
+        gi = jnp.asarray(prog_idx, jnp.int32)
+        B = int(mem_init.shape[0])
+        # Lane-blocked dispatch: big packed batches spill the per-lane
+        # state (mem image + registers) out of cache, so the cached
+        # executable is driven over <= blk_b-lane blocks and the results
+        # concatenated -- bit-identical (lanes are independent) and still
+        # one trace (every block has the same padded shape).  Skipped
+        # under an outer jit/shard_map (mesh path): blocking is a
+        # dispatch-level optimization and python-slicing a sharded
+        # operand would just reshard.
+        if (blk_b is None or B <= blk_b
+                or isinstance(mem_init, jax.core.Tracer)):
+            return core(fused, plen, tbl, mem_init, hw, gi)
+        nblk = -(-B // blk_b)
+        bs = -(-B // nblk)
+        pad = nblk * bs - B
+
+        def padlanes(x):
+            x = jnp.asarray(x)
+            if pad == 0:
+                return x
+            return jnp.concatenate(
+                [x, jnp.repeat(x[:1], pad, axis=0)], axis=0)
+
+        mem_p = padlanes(mem_init)
+        hw_p = jax.tree.map(padlanes, hw)
+        gi_p = padlanes(gi)
+        parts = [core(fused, plen, tbl,
+                      mem_p[i * bs:(i + 1) * bs],
+                      jax.tree.map(lambda x: x[i * bs:(i + 1) * bs], hw_p),
+                      gi_p[i * bs:(i + 1) * bs])
+                 for i in range(nblk)]
+        out = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
+        return jax.tree.map(lambda x: x[:B], out)
+
     if isinstance(program, Program):
-        # single-program API: seed-style constant-closure fast path
-        vfn = _xla_single_sweep_fn(program, profile, rows, cols, mem_size,
-                                   max_steps, chunk, max_banks)
+        batch_fn = fn
 
         def fn(mem_init, hw: HwConfig) -> SweepResult:
-            if validate:
-                validate_bank_bound(hw.n_banks, max_banks,
-                                    where="dse.make_sweep_fn(backend='xla')")
-            return vfn(mem_init, hw)
-    else:
-        batch = as_program_batch(program)
-        fused = jnp.asarray(fused_rows(batch_tables(batch)))  # (G*T, NF, P)
-        plen = jnp.asarray(batch.n_instrs, jnp.int32)         # (G,)
-        tbl = _profile_tables(profile)
-        core = _xla_sweep_core(rows, cols, mem_size, max_steps, chunk,
-                               max_banks, batch.t_max)
-
-        def fn(mem_init, hw: HwConfig, prog_idx) -> SweepResult:
-            if validate:
-                validate_bank_bound(hw.n_banks, max_banks,
-                                    where="dse.make_sweep_fn(backend='xla')")
-            gi = jnp.asarray(prog_idx, jnp.int32)
-            B = int(mem_init.shape[0])
-            # Lane-blocked dispatch: big packed batches spill the
-            # per-lane state (mem image + registers) out of cache, so
-            # the cached executable is driven over <= blk_b-lane blocks
-            # and the results concatenated -- bit-identical (lanes are
-            # independent) and still one trace (every block has the
-            # same padded shape).  Skipped under an outer jit/shard_map
-            # (mesh path): blocking is a dispatch-level optimization
-            # and python-slicing a sharded operand would just reshard.
-            if (blk_b is None or B <= blk_b
-                    or isinstance(mem_init, jax.core.Tracer)):
-                return core(fused, plen, tbl, mem_init, hw, gi)
-            nblk = -(-B // blk_b)
-            bs = -(-B // nblk)
-            pad = nblk * bs - B
-
-            def padlanes(x):
-                x = jnp.asarray(x)
-                if pad == 0:
-                    return x
-                return jnp.concatenate(
-                    [x, jnp.repeat(x[:1], pad, axis=0)], axis=0)
-
-            mem_p = padlanes(mem_init)
-            hw_p = jax.tree.map(padlanes, hw)
-            gi_p = padlanes(gi)
-            parts = [core(fused, plen, tbl,
-                          mem_p[i * bs:(i + 1) * bs],
-                          jax.tree.map(lambda x: x[i * bs:(i + 1) * bs],
-                                       hw_p),
-                          gi_p[i * bs:(i + 1) * bs])
-                     for i in range(nblk)]
-            out = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0),
-                               *parts)
-            return jax.tree.map(lambda x: x[:B], out)
+            return batch_fn(mem_init, hw, jnp.zeros(
+                (jnp.shape(mem_init)[0],), jnp.int32))
 
     if reduce is not None:
         # Compose the cached jitted segmented reducer over the core's
@@ -407,35 +378,40 @@ class GridPlan(NamedTuple):
         return int(self.img_idx.shape[0])
 
 
+def _grid(batch: ProgramBatch, fields, images) -> GridPlan:
+    """One batch's flat grid over hardware fields stacked once
+    (``hwconfig._stack_fields``, ``(H,)`` each) and the device-resident
+    ``(D, M)`` images: a campaign's length buckets share both."""
+    G, H, D = batch.n_programs, len(fields["n_banks"]), images.shape[0]
+    max_banks = scoreboard_bound(max(int(fields["n_banks"].max()),
+                                     DEFAULT_MAX_BANKS))
+    # broadcast to the full flat grid on the host: hw h repeats over the
+    # data axis, then the (hw x data) block tiles over the program axis;
+    # each field then reaches the device in one transfer
+    hw_grid = jax.device_put(HwConfig(**{
+        f: np.tile(np.repeat(x, D), G) for f, x in fields.items()}))
+    img_idx = np.tile(np.arange(D, dtype=np.int32), G * H)          # (G*H*D,)
+    prog_idx = np.repeat(np.arange(G, dtype=np.int32), H * D)
+    return GridPlan(batch, images, img_idx, prog_idx, hw_grid, max_banks)
+
+
 def plan_grid(program: Union[Program, ProgramBatch, Sequence[Program], None]
               = None, hw_configs: Sequence[HwConfig] = None,
               mem_images: np.ndarray = None, *,
               programs: Optional[Sequence[Program]] = None) -> GridPlan:
     """Flatten the (program x hw x data) grid to ``B = G*H*D`` index rows
     (row ``(g*H + h)*D + d``) without materializing any tiled images or
-    tables.  ``sweep()`` consumes the whole plan in one call; the sweep
-    service slices it into checkpointable work units."""
+    tables.  The sweep service slices it into checkpointable work units;
+    ``make_bucketed_sweep_fn`` builds one per length bucket."""
     with obs.span("dse.plan"):
         if programs is not None:
             if program is not None:
                 raise TypeError("plan_grid(): pass either program or "
                                 "programs=, not both")
             program = list(programs)
-        batch = as_program_batch(program)
-        G = batch.n_programs
-        H, D = len(hw_configs), mem_images.shape[0]
-        fields = _stack_fields(list(hw_configs))
-        max_banks = scoreboard_bound(max(int(fields["n_banks"].max()),
-                                         DEFAULT_MAX_BANKS))
-        # broadcast to the full flat grid on the host: hw h repeats over
-        # the data axis, then the (hw x data) block tiles over the program
-        # axis; each field then reaches the device in one transfer
-        hw_grid = jax.device_put(HwConfig(**{
-            f: np.tile(np.repeat(x, D), G) for f, x in fields.items()}))
-        images = jnp.asarray(mem_images, jnp.int32)          # (D, M), one copy
-        img_idx = np.tile(np.arange(D, dtype=np.int32), G * H)      # (G*H*D,)
-        prog_idx = np.repeat(np.arange(G, dtype=np.int32), H * D)
-        return GridPlan(batch, images, img_idx, prog_idx, hw_grid, max_banks)
+        return _grid(as_program_batch(program),
+                     _stack_fields(list(hw_configs)),
+                     jnp.asarray(mem_images, jnp.int32))
 
 
 def _shard_call(fn, images, mesh, reduce=None):
@@ -448,13 +424,12 @@ def _shard_call(fn, images, mesh, reduce=None):
     divides the device count.  Unreduced, the result stays sharded over
     the mesh.  Reduced, every device reduces its shard to a ``(G, K)``
     candidate set on device (padded lanes carry ``lane_idx = -1``) and
-    only the gathered ``n_devices * G * K`` candidates reach the host,
-    where ``merge_reduced`` recovers exactly the monolithic answer."""
+    the call returns them stacked ``(n_devices, G, K)``, for
+    ``_merge_candidates`` to merge on the host."""
     from jax.sharding import PartitionSpec
 
     from ..parallel.sharding import flat_batch_spec
     flat = flat_batch_spec(mesh)
-    n_dev = int(mesh.devices.size)
 
     def shard_fn(imgs, idx, gi, hw, *lane):
         out = fn(jnp.take(imgs, idx, axis=0), hw, gi, *lane)
@@ -468,22 +443,32 @@ def _shard_call(fn, images, mesh, reduce=None):
         out_specs=flat, check_vma=False))
 
     def call(idx, hw, gi, *lane):
-        with obs.span("dse.dispatch"):
-            out = sharded(images, jnp.asarray(idx, jnp.int32), jnp.asarray(
-                gi, jnp.int32), hw, *(jnp.asarray(x, jnp.int32)
-                                      for x in lane))
-        if reduce is None:
-            return out
-        with obs.span("dse.wait"):
-            out = jax.block_until_ready(out)
-        with obs.span("dse.merge"):
-            stacked = [np.asarray(leaf) for leaf in out]
-            parts = [_pareto.ReducedResult(*(leaf[i] for leaf in stacked))
-                     for i in range(n_dev)]
-            return _pareto.merge_reduced(reduce, parts)
+        return sharded(images, jnp.asarray(idx, jnp.int32),
+                       jnp.asarray(gi, jnp.int32), hw,
+                       *(jnp.asarray(x, jnp.int32) for x in lane))
 
     call.sharded = sharded        # the SPMD program, for AOT compilation
     return call
+
+
+def _merge_candidates(reduce: _pareto.Reduction, outs, groups,
+                      n_programs: int) -> _pareto.ReducedResult:
+    """THE host merge of every reduced sweep.  Waits once for the
+    device's candidate sets, places each one's rows in the global
+    segment space (``outs[i]`` reduced the programs ``groups[i]``; its
+    leaves are ``(G_i, K)``, or ``(n_devices, G_i, K)`` from a mesh) and
+    merges them.  Candidates already carry canonical flat indices."""
+    with obs.span("dse.wait"):
+        outs = jax.block_until_ready(outs)
+    with obs.span("dse.merge"):
+        parts = []
+        for out, rows in zip(outs, groups):
+            leaves = [np.asarray(x) for x in out]
+            per_dev = zip(*leaves) if np.ndim(out.count) == 2 else [leaves]
+            parts += [_pareto.remap_segments(
+                _pareto.ReducedResult(*p), rows,
+                np.zeros(len(rows), np.int64), n_programs) for p in per_dev]
+        return _pareto.merge_reduced(reduce, parts)
 
 
 def make_grid_fn(plan: GridPlan, profile: Profile, *,
@@ -524,7 +509,162 @@ def make_grid_fn(plan: GridPlan, profile: Profile, *,
                       *(jnp.asarray(x, jnp.int32) for x in lane))
         return grid_fn
 
-    return _shard_call(fn, images, mesh, reduce)
+    call = _shard_call(fn, images, mesh, reduce)
+    rows = tuple(range(plan.batch.n_programs))
+
+    def mesh_fn(*args):
+        with obs.span("dse.dispatch"):
+            out = call(*args)
+        return out if reduce is None else _merge_candidates(
+            reduce, [out], [rows], len(rows))
+    return mesh_fn
+
+
+def _program_list(program) -> list:
+    """A campaign's programs, unpacked: buckets pack themselves."""
+    if isinstance(program, Program):
+        return [program]
+    if isinstance(program, ProgramBatch):
+        return [program.program(g) for g in range(program.n_programs)]
+    return list(program)
+
+
+def make_bucketed_sweep_fn(programs, profile: Profile,
+                           hw_configs: Sequence[HwConfig],
+                           mem_images: np.ndarray, *,
+                           mesh: Optional[jax.sharding.Mesh] = None,
+                           max_steps: int = 2048, mem_size: int = 4096,
+                           backend: str = "xla",
+                           chunk_steps: Union[int, None, str] = AUTO,
+                           blk_b: Union[int, str] = AUTO,
+                           max_buckets: Union[int, str] = AUTO,
+                           interpret: Optional[bool] = None,
+                           reduce: Optional[_pareto.Reduction] = None,
+                           observed_steps: Optional[Sequence[int]] = None):
+    """THE campaign plan: ``fn() -> SweepResult | ReducedResult``, built
+    once and run any number of times.  ``sweep()`` is one call of it; a
+    steady-state loop (a benchmark, a mapping search) holds it.
+
+    Building it (one ``dse.plan`` span) resolves the backend and the
+    knobs once for the campaign and once per bucket through the autotune
+    cache (``backend=AUTO``: the cached xla-vs-pallas winner, else
+    ``"xla"``; nothing is timed here), splits the programs into length
+    buckets (``program.bucket_programs``; ``observed_steps`` buckets by
+    trip count), stacks the H hardware configurations once, packs each
+    bucket once and builds its grid, its sweep function and its
+    device-resident lane operands.  With ``mesh`` each bucket's function
+    is the ``shard_map`` program (``_shard_call``) over lane rows padded
+    to the device count.
+
+    Calling it dispatches every bucket (``dse.bucket`` spans), then
+    waits once.  Unreduced, lanes come back in canonical ``(g*H + h)*D
+    + d`` order: a single bucket's arrays as they are (on device,
+    sharded over the mesh), several buckets scattered on the host.
+    With ``reduce`` each bucket reduces itself on device (its lanes
+    carry canonical flat indices) and ``_merge_candidates`` merges the
+    K-sized candidate sets -- the ``(B,)`` grid never leaves the device.
+
+    The fn exposes the plan: ``fn.buckets`` (``ProgramBuckets``),
+    ``fn.bucket_fns`` (per bucket ``(sweep_fn, mems, hw, prog_idx)``;
+    on a mesh ``mems`` is the image rows each shard gathers by),
+    ``fn.bucket_cfgs`` (per-bucket ``TunedConfig``) and ``fn.reduce``."""
+    with obs.span("dse.plan"):
+        progs = _program_list(programs)
+        if len({p.n_pes for p in progs}) > 1:
+            raise ValueError("all programs of a campaign must target the "
+                             "same array (n_pes differs)")
+        G = len(progs)
+        H, D = len(hw_configs), int(mem_images.shape[0])
+        n_dev = int(mesh.devices.size) if mesh is not None else 1
+        t_max = max(p.n_instrs for p in progs)
+        cache = default_cache()
+        if is_auto(backend):
+            backend = resolve_backend(ShapeClass(
+                G=G, t_max=t_max, H=H, D=D, backend=AUTO, n_devices=n_dev),
+                cache=cache)
+        top = cache.resolve(
+            ShapeClass(G=G, t_max=t_max, H=H, D=D, backend=backend,
+                       n_devices=n_dev),
+            blk_b=blk_b, chunk_steps=chunk_steps, max_buckets=max_buckets)
+        buckets = bucket_programs(progs, top.max_buckets if G > 1 else 1,
+                                  observed_steps=observed_steps)
+        fields = _stack_fields(list(hw_configs))
+        images = jnp.asarray(mem_images, jnp.int32)
+        block = H * D
+        bucket_fns, bucket_cfgs, bucket_lanes = [], [], []
+        for b, rows in zip(buckets.batches, buckets.groups):
+            plan = _grid(b, fields, images)
+            cfg = cache.resolve(
+                ShapeClass(G=b.n_programs, t_max=b.t_max, H=H, D=D,
+                           backend=backend, n_devices=n_dev),
+                blk_b=blk_b, chunk_steps=chunk_steps, max_buckets=1)
+            # validate=False: every config was checked against the plan's
+            # derived scoreboard bound, so no runtime guard is staged
+            fnb = make_sweep_fn(b, profile, mem_size=mem_size,
+                                max_steps=max_steps, backend=backend,
+                                chunk_steps=cfg.chunk_steps, blk_b=cfg.blk_b,
+                                interpret=interpret, max_banks=plan.max_banks,
+                                validate=False, reduce=reduce)
+            # canonical flat indices of this bucket's lanes, so its
+            # candidates come back tagged in global coordinates
+            lane = np.concatenate([np.arange(g * block, (g + 1) * block,
+                                             dtype=np.int32) for g in rows])
+            idx, hw, gi = plan.img_idx, plan.hw_grid, plan.prog_idx
+            if mesh is None:
+                args = (jnp.take(images, jnp.asarray(idx), axis=0), hw,
+                        jnp.asarray(gi))
+            else:
+                # the mesh needs lane rows divisible by the device count:
+                # pad with duplicate (independent) lanes, masked from any
+                # reduction by lane_idx = -1 and sliced off raw results
+                from ..parallel.sharding import pad_batch, padded_len
+                Bp = padded_len(plan.n_lanes, n_dev)
+                fnb = _shard_call(fnb, images, mesh, reduce)
+                args = (pad_batch(jnp.asarray(idx), Bp),
+                        jax.tree.map(lambda x: pad_batch(x, Bp), hw),
+                        pad_batch(jnp.asarray(gi), Bp))
+                lane = pad_batch(jnp.asarray(lane), Bp, fill=-1)
+            bucket_fns.append((fnb,) + args)
+            bucket_cfgs.append(cfg)
+            bucket_lanes.append(() if reduce is None
+                                else (jnp.asarray(lane),))
+
+    def fn():
+        outs = []
+        for bi, (f, *args) in enumerate(bucket_fns):
+            with obs.span("dse.bucket", bucket=bi), obs.span("dse.dispatch"):
+                outs.append(f(*args, *bucket_lanes[bi]))
+        if reduce is not None:
+            return _merge_candidates(reduce, outs, buckets.groups, G)
+        if len(outs) == 1:
+            out = outs[0]
+            if mesh is None or out.latency_cc.shape[0] == G * block:
+                return out                 # stays sharded over the mesh
+            # a padded grid no longer splits evenly: replicate, then slice
+            from ..parallel.sharding import replicated_sharding
+            rep = replicated_sharding(mesh)
+            return jax.tree.map(lambda x: jax.device_put(x, rep)[:G * block],
+                                out)
+        with obs.span("dse.wait"):
+            outs = jax.block_until_ready(outs)
+
+        def scatter(*leaves):
+            out = np.empty((G * block,), np.asarray(leaves[0]).dtype)
+            for leaf, rows in zip(leaves, buckets.groups):
+                a = np.asarray(leaf)
+                for j, g in enumerate(rows):
+                    out[g * block:(g + 1) * block] = \
+                        a[j * block:(j + 1) * block]
+            return jnp.asarray(out)
+
+        with obs.span("dse.merge"):
+            return jax.tree.map(scatter, *outs)
+
+    fn.buckets = buckets
+    fn.bucket_fns = bucket_fns
+    fn.bucket_cfgs = bucket_cfgs
+    fn.reduce = reduce
+    return fn
 
 
 def sweep(program: Union[Program, ProgramBatch, Sequence[Program], None]
@@ -538,76 +678,69 @@ def sweep(program: Union[Program, ProgramBatch, Sequence[Program], None]
           chunk_steps: Union[int, None, str] = AUTO,
           blk_b: Union[int, str] = AUTO,
           max_buckets: Union[int, str] = AUTO,
-          autotune: Optional[bool] = None,
           interpret: Optional[bool] = None,
           reduce: Optional[_pareto.Reduction] = None,
           observed_steps: Optional[Sequence[int]] = None,
           mappings: Optional[MappingSet] = None,
           fold_mappings: bool = True
           ) -> Union[SweepResult, _pareto.ReducedResult]:
-    """Run the full (program x hw x data) grid through the lru-cached
-    operand core(s), optionally sharded over every device of a mesh.
+    """Run the full (program x hw x data) grid once: one call of the
+    campaign plan (``make_bucketed_sweep_fn``), optionally sharded over
+    every device of a mesh.
 
     program/programs: a single ``Program``, a sequence of programs, or a
     prebuilt ``ProgramBatch`` (``programs=`` is a keyword alias for call
     sites that sweep many kernels).  mem_images: (D, mem_size).  The
     grid is flattened to ``B = G*H*D``, row ``(g*H + h)*D + d`` pairing
     programs[g] with hw_configs[h] and mem_images[d]; a single program
-    keeps the legacy ``h*D + d`` layout (G=1).
+    is G = 1.
 
     The grid is broadcast *by index* on both the data and program axes:
     the D distinct memory images and the fused ``(G*T_max, N_ROW_FIELDS,
     P)`` row table go to the device(s) once, and each design point
     gathers its image and (one row per step, at ``prog_idx * T_max +
     pc``) its kernel's instructions inside the jitted program -- the
-    host never materializes tiled copies.  The unsharded multi-program
-    path calls the cached operand core *eagerly* (no per-call grid
-    wrapper to re-jit), so repeated sweeps of any same-padded-shape
-    kernel set are steady-state: zero compiles, zero retraces
-    (``TRACE_COUNTS``).
+    host never materializes tiled copies.  Every kernel set runs through
+    the lru-cached operand cores, so repeated sweeps of any
+    same-padded-shape kernel set are steady-state: zero compiles, zero
+    retraces (``TRACE_COUNTS``).
 
     chunk_steps / blk_b / max_buckets default to ``autotune.AUTO``: they
     resolve through the per-shape-class autotune cache
-    (``core.autotune``), falling back to the static defaults (64 / 128 /
-    4) when the shape was never tuned.  Pass concrete values to pin
-    knobs (``chunk_steps=None`` still means "disable chunking").  With
-    ``autotune=True`` (or ``REPRO_AUTOTUNE=1``) an untuned multi-program
-    shape is timed across a small candidate grid first and the winner is
-    persisted for every later call of that shape.  ``backend=AUTO``
-    makes the engine choice itself a tuned knob: an explicit backend
-    always wins, a cached xla-vs-pallas winner for this shape class is
-    used next, and with tuning opted in an unseen shape times both
-    engines once (``tune_sweep(backend=AUTO)``); otherwise ``"xla"``.
+    (``core.autotune``), falling back to the static defaults when the
+    shape was never tuned (``tune_sweep`` fills the cache ahead of
+    time).  Pass concrete values to pin knobs (``chunk_steps=None``
+    still means "disable chunking").  ``backend=AUTO`` takes the cached
+    xla-vs-pallas winner of this shape class, else ``"xla"``.
 
     max_buckets > 1 splits a multi-kernel sweep into up to that many
     length buckets (``program.bucket_programs``): each bucket packs to
     its own (smaller) ``t_max`` and runs through its own cached core, so
     short kernels stop convoying behind the longest kernel of the whole
-    set.  Results are scattered back to the canonical ``(g*H + h)*D + d``
-    row order and are bit-identical to the unbucketed sweep; compiled
-    cores grow by at most the number of buckets, not G.
+    set.  Results come back in the canonical ``(g*H + h)*D + d`` row
+    order, bit-identical to the unbucketed sweep; compiled cores grow by
+    at most the number of buckets, not G.
 
     Mesh sharding works the same way for both backends (``_shard_call``):
     under ``jax.shard_map`` each device sweeps its shard of the flat
     grid through its own engine with an independent early-exit loop.
-    Results are identical on 1 and N devices and stay sharded over the
-    mesh; a grid that does not divide the device count is padded with
-    duplicate lanes and sliced back.
+    Results are identical on 1 and N devices; a grid that does not
+    divide the device count is padded with duplicate lanes and sliced
+    back.
 
-    The bank-scoreboard bound of the contention model is derived here from
+    The bank-scoreboard bound of the contention model is derived from
     the configs (padded to a power of two); configs beyond the hard
     ceiling fail with an assertion instead of silently aliasing.
 
     reduce: an ``analysis.pareto`` spec (``TopK(objective, k)`` /
     ``ParetoFront(axes, max_points)``).  The per-program reduction runs
-    on device inside the compiled sweep -- per bucket when bucketed, per
-    device on a mesh -- and only the ``O(G*K)`` candidate sets are
-    merged on the host (``merge_reduced``), so the ``(B,)`` grid never
-    leaves the device.  Returns a host-resident ``ReducedResult`` whose
-    candidates are tagged with their canonical flat grid index
-    ``(g*H + h)*D + d``; results are bit-identical to reducing the
-    unreduced sweep with the numpy oracle, for any bucketing, mesh, or
-    backend.
+    on device inside the compiled sweep -- per bucket, per device on a
+    mesh -- and only the ``O(G*K)`` candidate sets are merged on the
+    host (``merge_reduced``), so the ``(B,)`` grid never leaves the
+    device.  Returns a host-resident ``ReducedResult`` whose candidates
+    are tagged with their canonical flat grid index ``(g*H + h)*D + d``;
+    results are bit-identical to reducing the unreduced sweep with the
+    numpy oracle, for any bucketing, mesh, or backend.
 
     observed_steps: optional per-program observed ``steps_executed``
     maxima from a prior run; when given, length bucketing groups by
@@ -634,280 +767,24 @@ def sweep(program: Union[Program, ProgramBatch, Sequence[Program], None]
             if program is not None or programs is not None:
                 raise TypeError(
                     "sweep: pass mappings= OR program(s)=, not both")
-            res = sweep(programs=list(mappings.programs), profile=profile,
-                        hw_configs=hw_configs, mem_images=mem_images,
-                        mesh=mesh, max_steps=max_steps, mem_size=mem_size,
-                        backend=backend, chunk_steps=chunk_steps, blk_b=blk_b,
-                        max_buckets=max_buckets, autotune=autotune,
-                        interpret=interpret, reduce=reduce,
-                        observed_steps=observed_steps)
-            if reduce is not None and fold_mappings:
-                return _pareto.fold_segments(reduce, res, mappings.kernel_of,
-                                             mappings.n_kernels)
-            return res
-        plan = plan_grid(program, hw_configs, mem_images, programs=programs)
-        batch = plan.batch
-        G = batch.n_programs
-        sp.set_metadata(G=G)
-        H, D = len(hw_configs), mem_images.shape[0]
-        n_dev = int(mesh.devices.size) if mesh is not None else 1
-
-        cache = default_cache()
-        if is_auto(backend):
-            # backend itself is a tuned knob: explicit > cached winner >
-            # (with tuning opted in) time xla-vs-pallas now > default xla
-            auto_shape = ShapeClass(G=G, t_max=batch.t_max, H=H, D=D,
-                                    backend=AUTO, n_devices=n_dev)
-            cached_b = cache.lookup(auto_shape)
-            if cached_b is not None and cached_b.backend in ("xla", "pallas"):
-                backend = cached_b.backend
-            elif autotune_enabled(autotune) and G > 1:
-                cfg_b = tune_sweep(batch, profile, hw_configs, mem_images,
-                                   backend=AUTO, max_steps=max_steps,
-                                   mem_size=mem_size, mesh=mesh,
-                                   interpret=interpret, cache=cache)
-                backend = cfg_b.backend or "xla"
-            else:
-                backend = "xla"
-
-        shape = ShapeClass(G=G, t_max=batch.t_max, H=H, D=D, backend=backend,
-                           n_devices=n_dev)
-        cfg = cache.resolve(shape, blk_b=blk_b, chunk_steps=chunk_steps,
-                            max_buckets=max_buckets)
-        if (autotune_enabled(autotune) and cfg.source == "default" and G > 1
-                and is_auto(blk_b, chunk_steps, max_buckets)):
-            # first encounter of an untuned shape with tuning opted in: time
-            # the candidate grid once, persist, and run with the winner
-            cfg = tune_sweep(batch, profile, hw_configs, mem_images,
-                             backend=backend, max_steps=max_steps,
-                             mem_size=mem_size, mesh=mesh, interpret=interpret,
-                             cache=cache)
-
-        if G > 1 and cfg.max_buckets > 1:
-            buckets = bucket_programs([batch.program(g) for g in range(G)],
-                                      cfg.max_buckets,
-                                      observed_steps=observed_steps)
-            if buckets.n_buckets > 1:
-                block = H * D
-                # Forward the caller's original chunk/blk knobs (AUTO or
-                # explicit), not the resolved top-level values: each bucket
-                # is its own shape class (G=n_b, its own t_max), so an AUTO
-                # knob picks up that bucket's tuned winner -- a short-kernel
-                # bucket can run a smaller chunk_steps than a long one.
-                parts = []
-                for bi, b in enumerate(buckets.batches):
-                    with obs.span("dse.bucket", bucket=bi):
-                        parts.append(sweep(
-                            program=b, profile=profile,
-                            hw_configs=hw_configs, mem_images=mem_images,
-                            mesh=mesh, max_steps=max_steps,
-                            mem_size=mem_size, backend=backend,
-                            chunk_steps=chunk_steps, blk_b=blk_b,
-                            max_buckets=1, autotune=False,
-                            interpret=interpret, reduce=reduce))
-
-                if reduce is not None:
-                    # Each bucket reduced itself on device; lift its rows
-                    # into the global segment space (bucket-local program j
-                    # maps to canonical program g, shifting candidate flat
-                    # indices by the row-block offset) and merge the K-sized
-                    # candidate sets -- never B-sized grids -- on the host.
-                    with obs.span("dse.merge"):
-                        placed = [
-                            _pareto.remap_segments(
-                                part, buckets.groups[bi],
-                                [(g - j) * block
-                                 for j, g in enumerate(buckets.groups[bi])],
-                                G)
-                            for bi, part in enumerate(parts)]
-                        return _pareto.merge_reduced(reduce, placed)
-
-                def scatter(*leaves):
-                    out = None
-                    for bi, leaf in enumerate(leaves):
-                        a = np.asarray(leaf)
-                        if out is None:
-                            out = np.empty((G * block,) + a.shape[1:], a.dtype)
-                        for j, g in enumerate(buckets.groups[bi]):
-                            out[g * block:(g + 1) * block] = \
-                                a[j * block:(j + 1) * block]
-                    return jnp.asarray(out)
-
-                with obs.span("dse.merge"):
-                    return jax.tree.map(scatter, *parts)
-
-        images = plan.images
-        img_idx = jnp.asarray(plan.img_idx)
-        prog_idx = jnp.asarray(plan.prog_idx)
-        hw_grid = plan.hw_grid
-        # validate=False: every config was checked against the plan's derived
-        # scoreboard bound, so no runtime guard needs to be staged into the
-        # compiled sweep
-        kw = dict(max_steps=max_steps, mem_size=mem_size, backend=backend,
-                  chunk_steps=cfg.chunk_steps, blk_b=cfg.blk_b,
-                  interpret=interpret, max_banks=plan.max_banks,
-                  validate=False)
-        # The constant-closure fast path is reserved for callers that hand us
-        # a bare Program (the legacy single-kernel API).  A 1-element batch
-        # or list goes through the operand core instead, so single-program
-        # buckets of a bucketed sweep share the cached executables.  A
-        # reduced sweep always uses the operand core (the reducer keys its
-        # segments on the prog_idx operand).
-        single_const = (programs is None and isinstance(program, Program)
-                        and reduce is None)
-        if single_const:
-            fn1 = make_sweep_fn(program, profile, **kw)
-            fn = lambda mem, hw, gi: fn1(mem, hw)
-        else:
-            fn = make_sweep_fn(batch, profile, **kw, reduce=reduce)
-
-        if mesh is None:
-            if reduce is not None:
-                lane_idx = jnp.arange(G * H * D, dtype=jnp.int32)
-                with obs.span("dse.dispatch"):
-                    red = fn(jnp.take(images, img_idx, axis=0), hw_grid,
-                             prog_idx, lane_idx)
-                # merge_reduced reads the candidates on the host: the
-                # sweep's wait for the device happens here
-                with obs.span("dse.wait"):
-                    red = jax.block_until_ready(red)
-                with obs.span("dse.merge"):
-                    return _pareto.merge_reduced(reduce, [red])
-            with obs.span("dse.dispatch"):
-                if single_const:
-                    # legacy data flow: the constant-closure vfn is
-                    # unjitted by design (tables fold into the
-                    # executable); jit the wrapper
-                    return jax.jit(lambda idx, hw, gi: fn(
-                        jnp.take(images, idx, axis=0), hw, gi))(
-                            img_idx, hw_grid, prog_idx)
-                # operand core: already jitted + lru-cached, so call it
-                # eagerly -- a per-call jit wrapper here would recompile
-                # the whole pipeline every sweep() call and forfeit the
-                # steady state
-                return fn(jnp.take(images, img_idx, axis=0), hw_grid,
-                          prog_idx)
-
-        from ..parallel.sharding import pad_batch, padded_len
-        # The mesh path needs the flat grid divisible by the device count;
-        # pad with duplicate (harmless, independent) lanes and slice back.
-        B = G * H * D
-        Bp = padded_len(B, int(mesh.devices.size))
-        img_idx = pad_batch(img_idx, Bp)
-        prog_idx = pad_batch(prog_idx, Bp)
-        hw_grid = jax.tree.map(lambda x: pad_batch(x, Bp), hw_grid)
-        call = _shard_call(fn, images, mesh, reduce)
-        if reduce is not None:
-            # duplicate pad lanes are masked via lane_idx = -1
-            lane_idx = pad_batch(jnp.arange(B, dtype=jnp.int32), Bp, fill=-1)
-            return call(img_idx, hw_grid, prog_idx, lane_idx)
-        res = call(img_idx, hw_grid, prog_idx)
-        if Bp == B:
-            return res                     # stays sharded over the mesh
-        # a padded grid no longer splits evenly: replicate, then slice
-        from ..parallel.sharding import replicated_sharding
-        rep = replicated_sharding(mesh)
-        return jax.tree.map(lambda x: jax.device_put(x, rep)[:B], res)
-
-
-def make_bucketed_sweep_fn(programs, profile: Profile,
-                           hw_configs: Sequence[HwConfig],
-                           mem_images: np.ndarray, *,
-                           max_steps: int = 2048, mem_size: int = 4096,
-                           backend: str = "xla",
-                           chunk_steps: Union[int, None, str] = AUTO,
-                           blk_b: Union[int, str] = AUTO,
-                           max_buckets: Union[int, str] = AUTO,
-                           interpret: Optional[bool] = None,
-                           reduce: Optional[_pareto.Reduction] = None,
-                           observed_steps: Optional[Sequence[int]] = None):
-    """Hold a bucketed packed plan: ``fn() -> SweepResult``.
-
-    ``sweep()`` re-packs, re-buckets, and re-resolves knobs on every
-    call -- fine for one-shot grids, pure overhead for a steady-state
-    loop (a service slot, a benchmark) that re-executes the *same*
-    kernel set.  This builds everything once -- length buckets, per-
-    bucket autotune-resolved knobs, per-bucket operand fns, device-
-    resident lane operands -- and returns a zero-argument callable that
-    executes the buckets and scatters lanes back to canonical
-    ``(g*H + h)*D + d`` order, bit-identical to ``sweep()``.
-
-    The returned fn exposes the plan for introspection: ``fn.buckets``
-    (``ProgramBuckets``), ``fn.bucket_fns`` (list of ``(sweep_fn, mems,
-    hw, prog_idx)`` operand tuples), ``fn.bucket_cfgs`` (per-bucket
-    ``TunedConfig``).  Unsharded only (a mesh shards *within* one
-    ``sweep`` call; hold one plan per mesh instead).
-
-    With ``reduce`` each bucket reduces itself on device (the lane
-    operands carry *canonical* flat grid indices, precomputed here once)
-    and ``fn() -> ReducedResult`` merges the K-sized per-bucket
-    candidate sets on the host -- the steady-state loop never touches a
-    ``(B,)`` array.  ``observed_steps`` buckets by trip count instead of
-    static length (see ``program.bucket_programs``)."""
-    batch = as_program_batch(programs)
-    G = batch.n_programs
-    H, D = len(hw_configs), int(mem_images.shape[0])
-    cache = default_cache()
-    top = cache.resolve(
-        ShapeClass(G=G, t_max=batch.t_max, H=H, D=D, backend=backend),
-        blk_b=blk_b, chunk_steps=chunk_steps, max_buckets=max_buckets)
-    buckets = bucket_programs([batch.program(g) for g in range(G)],
-                              top.max_buckets if G > 1 else 1,
-                              observed_steps=observed_steps)
-    block = H * D
-    bucket_fns, bucket_cfgs, bucket_lanes = [], [], []
-    for bi, b in enumerate(buckets.batches):
-        plan = plan_grid(b, hw_configs, mem_images)
-        cfgb = cache.resolve(
-            ShapeClass(G=b.n_programs, t_max=b.t_max, H=H, D=D,
-                       backend=backend),
-            blk_b=blk_b, chunk_steps=chunk_steps, max_buckets=1)
-        fnb = make_sweep_fn(b, profile, mem_size=mem_size,
-                            max_steps=max_steps, backend=backend,
-                            chunk_steps=cfgb.chunk_steps, blk_b=cfgb.blk_b,
-                            interpret=interpret, max_banks=plan.max_banks,
-                            validate=False, reduce=reduce)
-        mems = jnp.take(plan.images, jnp.asarray(plan.img_idx), axis=0)
-        bucket_fns.append((fnb, mems, plan.hw_grid,
-                           jnp.asarray(plan.prog_idx)))
-        bucket_cfgs.append(cfgb)
-        if reduce is not None:
-            # canonical flat indices of this bucket's lanes, so bucket
-            # candidates come back already tagged in global coordinates
-            bucket_lanes.append(jnp.asarray(np.concatenate(
-                [np.arange(g * block, (g + 1) * block, dtype=np.int32)
-                 for g in buckets.groups[bi]])))
-
-    if reduce is not None:
-        def fn() -> _pareto.ReducedResult:
-            placed = [
-                _pareto.remap_segments(
-                    f(m, h, gi, bucket_lanes[bi]), buckets.groups[bi],
-                    np.zeros(len(buckets.groups[bi]), np.int64), G)
-                for bi, (f, m, h, gi) in enumerate(bucket_fns)]
-            return _pareto.merge_reduced(reduce, placed)
-    else:
-        def fn() -> SweepResult:
-            parts = [f(m, h, gi) for f, m, h, gi in bucket_fns]
-
-            def scatter(*leaves):
-                out = None
-                for bi, leaf in enumerate(leaves):
-                    a = np.asarray(leaf)
-                    if out is None:
-                        out = np.empty((G * block,) + a.shape[1:], a.dtype)
-                    for j, g in enumerate(buckets.groups[bi]):
-                        out[g * block:(g + 1) * block] = \
-                            a[j * block:(j + 1) * block]
-                return jnp.asarray(out)
-
-            return jax.tree.map(scatter, *parts)
-
-    fn.buckets = buckets
-    fn.bucket_fns = bucket_fns
-    fn.bucket_cfgs = bucket_cfgs
-    fn.reduce = reduce
-    return fn
+            program = list(mappings.programs)
+        elif programs is not None:
+            if program is not None:
+                raise TypeError("sweep(): pass either program or "
+                                "programs=, not both")
+            program = programs
+        fn = make_bucketed_sweep_fn(
+            program, profile, hw_configs, mem_images, mesh=mesh,
+            max_steps=max_steps, mem_size=mem_size, backend=backend,
+            chunk_steps=chunk_steps, blk_b=blk_b, max_buckets=max_buckets,
+            interpret=interpret, reduce=reduce,
+            observed_steps=observed_steps)
+        sp.set_metadata(G=len(fn.buckets.assignment))
+        res = fn()
+        if mappings is not None and reduce is not None and fold_mappings:
+            return _pareto.fold_segments(reduce, res, mappings.kernel_of,
+                                         mappings.n_kernels)
+        return res
 
 
 # ---------------------------------------------------------------------------

@@ -88,9 +88,9 @@ def test_bucketed_sweep_spans_and_configs_stacked(grid, tmp_path):
                                       np.asarray(getattr(ref, f)),
                                       err_msg=f)
     H = len(grid["hw_configs"])
-    # one plan of the whole grid, then one per length bucket
-    assert (obs.COUNTS["hwconfig.configs_stacked"] - stacked0
-            == H * (1 + n_buckets))
+    # one campaign plan: the H configurations are stacked once, and the
+    # length buckets share them
+    assert obs.COUNTS["hwconfig.configs_stacked"] - stacked0 == H
 
     spans = _spans(tmp_path)
     names = ("dse.sweep", "dse.plan", "hwconfig.stack", "dse.bucket",
@@ -98,19 +98,23 @@ def test_bucketed_sweep_spans_and_configs_stacked(grid, tmp_path):
     for name in names:
         assert _named(spans, name), name
     sweeps = _named(spans, "dse.sweep")
-    outer = min(sweeps, key=lambda s: s[1])
+    assert len(sweeps) == 1
+    outer, = sweeps
     assert outer[4] == {"G": 2, "H": H, "D": 2}
-    assert len(sweeps) == 1 + n_buckets
-    assert sorted(s[4]["bucket"] for s in _named(spans, "dse.bucket")) \
-        == list(range(n_buckets))
-    assert [s[4]["n"] for s in _named(spans, "hwconfig.stack")] \
-        == [H] * (1 + n_buckets)
-    assert all(_inside(s, _named(spans, "dse.plan"))
-               for s in _named(spans, "hwconfig.stack"))
-    assert all(_inside(s, sweeps) for s in _named(spans, "dse.wait"))
-    # each bucket's sweep nests in its bucket span, inside the caller's
-    assert all(_inside(s, _named(spans, "dse.bucket"))
-               for s in sweeps if s is not outer)
+    stacks = _named(spans, "hwconfig.stack")
+    assert [s[4]["n"] for s in stacks] == [H]
+    assert all(_inside(s, _named(spans, "dse.plan")) for s in stacks)
+    buckets = _named(spans, "dse.bucket")
+    assert sorted(s[4]["bucket"] for s in buckets) == list(range(n_buckets))
+    # each bucket span holds its own dispatch, and nothing else dispatches
+    dispatches = _named(spans, "dse.dispatch")
+    assert len(dispatches) == n_buckets
+    assert all(sum(_inside(d, [b]) for d in dispatches) == 1
+               for b in buckets)
+    # the buckets are waited for and merged once, inside the sweep
+    for name in ("dse.wait", "dse.merge"):
+        assert len(_named(spans, name)) == 1, name
+        assert _inside(_named(spans, name)[0], [outer]), name
     assert all(_inside(s, [outer]) for s in spans if s[3] == outer[3])
 
 
